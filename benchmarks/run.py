@@ -47,6 +47,8 @@ def main() -> None:
                          "enforceable in CI)")
     args = ap.parse_args()
 
+    from repro.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig2_allocator_microbench,
                             fig3_fig4_thread_placement,
                             fig5_placement_policies,

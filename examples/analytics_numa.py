@@ -3,21 +3,15 @@ four memory placement policies + the AutoNUMA analogue, with wall times —
 a miniature of paper Figures 5/6.
 
     PYTHONPATH=src python examples/analytics_numa.py
-(re-executes itself with 8 fake devices)
+
+The mesh spans every device JAX sees in this process: the chips of a TPU
+host, or on a CPU the devices that
+XLA_FLAGS=--xla_force_host_platform_device_count=N asks for.
 """
 import os
-import subprocess
 import sys
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-if "XLA_FLAGS" not in os.environ:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = SRC
-    sys.exit(subprocess.run([sys.executable, __file__], env=env).returncode)
-
-sys.path.insert(0, SRC)
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import time
 import jax
@@ -26,6 +20,7 @@ import numpy as np
 
 from repro.analytics.datasets import blanas_join, moving_cluster
 from repro.analytics.engine import dist_count, dist_hash_join, dist_median
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.config import PlacementPolicy
 
 
@@ -40,7 +35,8 @@ def bench(fn, *args, iters=5):
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("data",))
+    enable_compile_cache()
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
     G, N = 4096, 1 << 20
     ds = moving_cluster(N, G, seed=3)
     keys, vals = jnp.asarray(ds.keys), jnp.asarray(ds.vals)

@@ -8,21 +8,15 @@ the ExecutionContext alone: naive XLA plan, cost-chosen fused kernels, or
 a distributed placement-policy backend on a device mesh.
 
     PYTHONPATH=src python examples/analytics_plan.py
-(re-executes itself with 8 fake devices)
+
+The mesh spans every device JAX sees in this process: the chips of a TPU
+host, or on a CPU the devices that
+XLA_FLAGS=--xla_force_host_platform_device_count=N asks for.
 """
 import os
-import subprocess
 import sys
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-if "XLA_FLAGS" not in os.environ:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = SRC
-    sys.exit(subprocess.run([sys.executable, __file__], env=env).returncode)
-
-sys.path.insert(0, SRC)
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 import numpy as np
@@ -30,7 +24,10 @@ import numpy as np
 from repro.analytics.plan import LogicalPlan, col, describe, scan
 from repro.analytics.planner import ExecutionContext, execute_plan, explain
 from repro.analytics.tpch import generate
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.config import PlacementPolicy
+
+enable_compile_cache()
 
 # A query that is NOT one of the five shipped TPC-H builders: revenue and
 # order count per customer nation for heavily-discounted recent lineitems.
@@ -58,15 +55,16 @@ for d in explain(plan, tables, local):
     print(" ", d.describe())
 out_local = execute_plan(plan, tables, local)
 
-# Context 2: SAME plan on an 8-device mesh under a placement policy.
-mesh = jax.make_mesh((8,), ("data",))
+# Context 2: SAME plan on a device mesh under a placement policy.
+n_dev = len(jax.devices())
+mesh = jax.make_mesh((n_dev,), ("data",))
 dist = ExecutionContext(executor="cost", mesh=mesh,
                         policy=PlacementPolicy.INTERLEAVE)
 out_dist = execute_plan(plan, tables, dist)
 
 print("\nrevenue by nation (local cost-based):")
 print(np.array2string(np.asarray(out_local["revenue"]), precision=0))
-print("revenue by nation (8-device mesh, INTERLEAVE policy):")
+print(f"revenue by nation ({n_dev}-device mesh, INTERLEAVE policy):")
 print(np.array2string(np.asarray(out_dist["revenue"]), precision=0))
 err = np.abs(np.asarray(out_local["revenue"])
              - np.asarray(out_dist["revenue"])).max()

@@ -17,21 +17,15 @@ service retries the build, requeues the dead pool's backlog, and keeps
 serving on the survivor (same results, counters tell the story).
 
     PYTHONPATH=src python examples/analytics_service.py
-(re-executes itself with 8 fake devices)
+
+The mesh spans every device JAX sees in this process: the chips of a TPU
+host, or on a CPU the devices that
+XLA_FLAGS=--xla_force_host_platform_device_count=N asks for.
 """
 import os
-import subprocess
 import sys
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-if "XLA_FLAGS" not in os.environ:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = SRC
-    sys.exit(subprocess.run([sys.executable, __file__], env=env).returncode)
-
-sys.path.insert(0, SRC)
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 import numpy as np
@@ -40,11 +34,13 @@ from repro.analytics.planner import ExecutionContext
 from repro.analytics.service import (AnalyticsService, ServiceConfig,
                                      ThreadPlacement)
 from repro.analytics.tpch import generate, run_query, submit_query
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.config import PlacementPolicy
 
+enable_compile_cache()
 data = generate(scale=0.01, seed=7)
 local = ExecutionContext(executor="cost")
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((len(jax.devices()),), ("data",))
 dist = ExecutionContext(executor="cost", mesh=mesh,
                         policy=PlacementPolicy.INTERLEAVE)
 

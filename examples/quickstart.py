@@ -6,8 +6,11 @@ communication plans, and a reduced-LM train step — all through the same
 application-agnostic knobs.
 
     PYTHONPATH=src python examples/quickstart.py
+
+The placement-policy step uses a mesh over every device JAX sees in this
+process (on a CPU, XLA_FLAGS=--xla_force_host_platform_device_count=N
+gives N).
 """
-import subprocess
 import sys
 import os
 
@@ -18,15 +21,18 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.analytics.aggregate import median_jit
-from repro.analytics.datasets import blanas_join, moving_cluster
+from repro.analytics.datasets import blanas_join, moving_cluster, zipf
+from repro.analytics.engine import dist_count
 from repro.analytics.join import hash_join
 from repro.configs.reduced import REDUCED
-from repro.core.config import LM_SHAPES, RunConfig, TrainConfig
+from repro.core.compile_cache import enable_compile_cache
+from repro.core.config import LM_SHAPES, PlacementPolicy, RunConfig, TrainConfig
 from repro.models.lm import LMModel
 from repro.runtime import train
 
 
 def main():
+    enable_compile_cache()
     print("== W1: holistic aggregation (GROUP BY median) ==")
     ds = moving_cluster(200_000, 4096, seed=0)
     med = median_jit(jnp.asarray(ds.keys), jnp.asarray(ds.vals), 4096)
@@ -42,23 +48,13 @@ def main():
     print(f"   matches: {int(cnt)}, checksum: {float(chk):.1f}, "
           f"overflow: {int(ovf)}")
 
-    print("== placement policies (8-device subprocess mesh) ==")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    code = """
-import jax, jax.numpy as jnp, numpy as np
-from repro.core.config import PlacementPolicy
-from repro.analytics.engine import dist_count
-from repro.analytics.datasets import zipf
-mesh = jax.make_mesh((8,), ("data",))
-ds = zipf(65536, 64, seed=2)
-keys = jnp.asarray(ds.keys)
-for pol in PlacementPolicy:
-    out = jax.jit(dist_count(mesh, pol, 64))(keys)
-    print(f"   {pol.value:12s} total={float(out.sum()):.0f}")
-"""
-    subprocess.run([sys.executable, "-c", code], env=env, check=True)
+    n_dev = len(jax.devices())
+    print(f"== placement policies ({n_dev}-device mesh) ==")
+    mesh = jax.make_mesh((n_dev,), ("data",))
+    keys = jnp.asarray(zipf(65536, 64, seed=2).keys)
+    for pol in PlacementPolicy:
+        out = jax.jit(dist_count(mesh, pol, 64))(keys)
+        print(f"   {pol.value:12s} total={float(out.sum()):.0f}")
 
     print("== reduced-LM train step (qwen2-family) ==")
     arch = REDUCED["qwen2-0.5b"]

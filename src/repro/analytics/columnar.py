@@ -10,9 +10,9 @@ lowers each logical node onto one of the operators here.
 Grouped aggregation has three physical layouts the planner chooses between
 per Aggregate node — see planner.choose_aggregate for the cost model:
 
-  "xla"          one XLA segment op per aggregate — the naive plan a query
-                 compiler emits without memory tuning. N passes over the
-                 table for N aggregates.
+  "xla"          one XLA segment scatter per summed column — the naive plan
+                 a query compiler emits without memory tuning. N passes
+                 over the table for N aggregates.
   "dense"        fused-kernel sweep with positionally-chunked full-width
                  tables (no sort; exact): every (sum, avg, count) aggregate
                  over one key column is stacked into a single values matrix
@@ -43,6 +43,7 @@ skewed keys stay exact — or is counted and surfaced with residual=False).
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Dict, Mapping, Optional, Tuple
 
@@ -55,9 +56,22 @@ from repro.kernels.hash_aggregate import hash_aggregate_multi
 from repro.kernels.join_probe import join_probe
 
 # Largest key domain aggregated with full-width per-chunk tables (the
-# one-hot is (block, n_bins): 512 x 4096 fp32 = 8 MB VMEM). Beyond this the
-# kernel path range-partitions so each partition table stays narrow.
+# kernel's one-hot is (n_bins, block/8): 4096 x 128 fp32 = 2 MB VMEM per
+# row). Beyond this the kernel path range-partitions so each partition
+# table stays narrow.
 DENSE_GROUP_LIMIT = 4096
+# Largest (chunks x groups) table of the XLA layout's chunked sums.
+SUM_CHUNK_TABLE = 1 << 22
+# Rows below which an f32 sum of 0/1 weights is an exact count.
+F32_EXACT_COUNT = 1 << 24
+# Widest partition table of the range-partitioned layout: the partition
+# count grows with the group count so the one-hot stays VMEM-sized.
+MAX_PARTITION_BINS = 2048
+# Kernel join bounds: the build tile each probe row is compared against
+# (the partition count grows with the build side to keep it), and the rows
+# per side (row positions ride through the kernel as exact f32 integers).
+MAX_BUILD_TILE = 4096
+KERNEL_JOIN_MAX_ROWS = 1 << 24
 
 
 @dataclass
@@ -151,6 +165,26 @@ def pkfk_join(fact: Table, dim: Table, fact_key: str, dim_key: str,
                  out.index_cache)
 
 
+def _capacity(rows: int, n_partitions: int, capacity_factor: float,
+              unit: int) -> int:
+    """Slots per partition: the capacity-factor share, rounded up to
+    ``unit``."""
+    share = int(rows // n_partitions * capacity_factor)
+    return int(max(unit, -(-share // unit) * unit))
+
+
+def join_layout(n_fact: int, n_dim: int, n_partitions: int,
+                capacity_factor: float) -> Tuple[int, int, int]:
+    """(partitions, build slots, probe slots) of the kernel join's dense
+    layouts. Partitions: ``n_partitions``, or the power of two that keeps
+    each build tile within MAX_BUILD_TILE keys. Probe slots are a multiple
+    of 1024, which the kernel folds into 8 rows of 128-lane multiples."""
+    tiles = -(-int(n_dim * capacity_factor) // MAX_BUILD_TILE)
+    P = max(n_partitions, 1 << max(0, tiles - 1).bit_length())
+    return (P, _capacity(n_dim, P, capacity_factor, 128),
+            _capacity(n_fact, P, capacity_factor, 1024))
+
+
 def pkfk_join_kernel(fact: Table, dim: Table, fact_key: str, dim_key: str,
                      take: Mapping[str, str], *, n_partitions: int = 32,
                      capacity_factor: float = 2.0,
@@ -166,6 +200,8 @@ def pkfk_join_kernel(fact: Table, dim: Table, fact_key: str, dim_key: str,
     mask) are gathered. Keys must be non-negative (key -1 is the padding
     sentinel).
 
+    The partitions and their capacities come from ``join_layout``.
+
     Rows beyond a partition's capacity — on either side — are dropped from
     the dense layouts and would degrade to join misses under key skew. With
     ``residual=True`` (default) a residual pass re-probes the kernel's
@@ -178,29 +214,28 @@ def pkfk_join_kernel(fact: Table, dim: Table, fact_key: str, dim_key: str,
     fk = fact.col(fact_key).astype(jnp.int32)
     dk = dim.col(dim_key).astype(jnp.int32)
     n_fact, n_dim = fk.shape[0], dk.shape[0]
-    if max(n_fact, n_dim) >= 1 << 24:
+    if max(n_fact, n_dim) >= KERNEL_JOIN_MAX_ROWS:
         # row positions ride through the kernel as float32 payloads, which
         # are only exact integers below 2^24 — beyond that positions would
         # silently collide; refuse rather than corrupt the join
         raise ValueError(f"pkfk_join_kernel limited to <2^24 rows per side, "
                          f"got fact={n_fact}, dim={n_dim}")
-    P = n_partitions
+    P, build_cap, probe_cap = join_layout(n_fact, n_dim, n_partitions,
+                                          capacity_factor)
 
-    def _layout(keys, payload, cap_rows):
+    def _layout(keys, payload, cap):
         part = partition_of(keys, P)
         order = jnp.argsort(part, stable=True)
         counts = jnp.bincount(part, length=P)
         starts = jnp.cumsum(counts) - counts
-        cap = int(max(128, -(-int(cap_rows // P * capacity_factor) // 128)
-                      * 128))
         return pad_partitions(keys[order], payload[order], starts, counts,
                               P, cap)
 
     # build side carries its own row positions as the probe payload
     bkeys, bpos, ovf_b = _layout(dk, jnp.arange(n_dim, dtype=jnp.float32),
-                                 n_dim)
+                                 build_cap)
     pkeys, prow, ovf_p = _layout(fk, jnp.arange(n_fact, dtype=jnp.float32),
-                                 n_fact)
+                                 probe_cap)
     vals, found = join_probe(bkeys, bpos, pkeys, mode=mode)
     # scatter per-slot results back to original row order; padding slots
     # (key -1) collide on a dummy row that is sliced off
@@ -256,41 +291,25 @@ def group_aggregate(table: Table, key: str, n_groups: int,
     ``_count`` and ``_overflow`` (records beyond partition capacity on the
     kernel path; always 0 on the XLA path and the dense kernel path).
 
-    ``layout`` overrides the kernel path's dense/partitioned choice (the
-    cost-based planner sets it per Aggregate node); None keeps the
-    DENSE_GROUP_LIMIT domain-size rule."""
-    if executor == "kernel":
-        return _group_aggregate_kernel(table, key, n_groups, aggs, mode=mode,
-                                       layout=layout,
-                                       n_partitions=n_partitions,
-                                       capacity_factor=capacity_factor)
-    if executor != "xla":
+    Both executors sum the same stacked matrix (``stacked_columns``): "xla"
+    with segment scatters, "kernel" with the fused kernel. ``layout``
+    overrides the kernel path's dense/partitioned choice (the cost-based
+    planner sets it per Aggregate node); None keeps the DENSE_GROUP_LIMIT
+    domain-size rule."""
+    if executor not in ("xla", "kernel"):
         raise ValueError(f"unknown executor {executor!r}")
-    return _group_aggregate_xla(table, key, n_groups, aggs)
-
-
-def _group_aggregate_xla(table: Table, key: str, n_groups: int,
-                         aggs: Mapping[str, Tuple[str, str]]
-                         ) -> Dict[str, jax.Array]:
-    """Default plan: one segment op per aggregate."""
-    keys = jnp.clip(table.col(key), 0, n_groups - 1)
-    w = table.weights()
-    out: Dict[str, jax.Array] = {}
-    cnt = jax.ops.segment_sum(w, keys, num_segments=n_groups)
-    for name, (op, col) in aggs.items():
-        if op == "count":
-            out[name] = cnt
-            continue
-        v = table.col(col).astype(jnp.float32)
-        if op in ("sum", "avg"):
-            s = jax.ops.segment_sum(v * w, keys, num_segments=n_groups)
-            out[name] = s if op == "sum" else s / jnp.maximum(cnt, 1.0)
-        elif op in ("max", "min") or is_holistic(op):
-            out[name] = segment_order_stat(table, keys, n_groups, op, col)
-        else:
-            raise ValueError(f"unknown agg op {op!r}")
-    out["_count"] = cnt
-    out["_overflow"] = jnp.zeros((), jnp.int32)
+    keys, vals, src = stacked_columns(table, key, n_groups, aggs)
+    if executor == "xla":
+        layout = "xla"
+    elif layout is None:
+        layout = "dense" if n_groups <= DENSE_GROUP_LIMIT else "partitioned"
+    sums, overflow = stacked_group_sums(
+        keys, vals, n_groups, layout=layout, mode=mode,
+        n_partitions=n_partitions, capacity_factor=capacity_factor)
+    out = finalize_stacked(
+        aggs, src, sums,
+        lambda op, col: segment_order_stat(table, keys, n_groups, op, col))
+    out["_overflow"] = overflow.astype(jnp.int32)
     return out
 
 
@@ -324,10 +343,14 @@ def stacked_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int, *,
     The single physical primitive every grouped-sum lowering shares: the
     local executor, the distributed per-shard partials (planner.py) and
     aggregate.count_partitioned all funnel through here. Returns
-    ((n_groups, C) sums, overflow)."""
+    ((n_groups, C) sums, overflow).
+
+    Column 0 (the 0/1 selection weights) comes back as the exact count,
+    rounded once to f32: each layout adds it in int32 wherever a partial
+    could pass 2^24, the last integer f32 accumulation keeps."""
     if layout == "xla":
-        return (jax.ops.segment_sum(vals, keys, num_segments=n_groups),
-                jnp.zeros((), jnp.int32))
+        return _segment_sums_xla(keys, vals, n_groups), \
+            jnp.zeros((), jnp.int32)
     if layout == "dense":
         return _fused_dense(keys, vals, n_groups, mode=mode), \
             jnp.zeros((), jnp.int32)
@@ -339,6 +362,29 @@ def stacked_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int, *,
     raise ValueError(f"unknown layout {layout!r}")
 
 
+def _segment_sums_xla(keys: jax.Array, vals: jax.Array,
+                      n_groups: int) -> jax.Array:
+    """(n_groups, C) sums of the stacked (N, C) matrix by segment scatters.
+
+    A scatter-add accumulates in f32 in row order, so one big group drifts
+    (a count stops at 2^24). Rows are cut into about sqrt(N) position
+    chunks, as many as keep the chunk tables within SUM_CHUNK_TABLE
+    entries; each chunk sums into its own table and the tables are added,
+    the count column in int32. One scatter per column: a scatter of the
+    (N, C) matrix makes the TPU compiler put C on the 128-lane axis."""
+    N, C = vals.shape
+    k = max(1, min(math.isqrt(N), SUM_CHUNK_TABLE // n_groups))
+    seg = (jnp.arange(N, dtype=jnp.int32) // -(-N // k)) * n_groups + keys
+
+    def chunk_sums(v):
+        return jax.ops.segment_sum(v, seg, num_segments=k * n_groups
+                                   ).reshape(k, n_groups).sum(axis=0)
+
+    counts = chunk_sums(vals[:, 0].astype(jnp.int32)).astype(jnp.float32)
+    return jnp.stack([counts] + [chunk_sums(vals[:, c]) for c in range(1, C)],
+                     axis=1)
+
+
 def _segment_selection(keys: jax.Array, vals: jax.Array, n_groups: int):
     """Shared sort pass of the order-statistic primitives: per-group
     value-sorted runs plus each run's (count, start). Keys < 0 are
@@ -346,26 +392,21 @@ def _segment_selection(keys: jax.Array, vals: jax.Array, n_groups: int):
     n_groups clip into the last group (the stacked_columns convention —
     the selection math needs the key order and the count clipping to
     agree, so the clip is enforced here, not left to callers). Returns
-    (sorted_vals, counts f32, starts i32 shifted past the excluded run,
-    sorted_keys i-dtype in the same order as sorted_vals)."""
+    (sorted_vals, counts i32, starts i32 shifted past the excluded run,
+    sorted_keys i-dtype in the same order as sorted_vals). Counts and starts
+    are integers: an f32 count stops at 2^24 rows."""
     keys = jnp.where(keys < 0, -1, jnp.minimum(keys, n_groups - 1))
     order_v = jnp.argsort(vals, stable=True)
     k1, v1 = keys[order_v], vals[order_v]
     order_k = jnp.argsort(k1, stable=True)
     sv = v1[order_k]
     sk = k1[order_k]
-    counts = jax.ops.segment_sum(
-        jnp.ones_like(keys, jnp.float32),
-        jnp.clip(keys, 0, n_groups - 1), num_segments=n_groups)
-    # discard excluded records (key < 0) from counts (clipped to group 0)
-    pad = jax.ops.segment_sum(
-        jnp.where(keys < 0, 1.0, 0.0),
-        jnp.zeros_like(keys), num_segments=n_groups)
-    counts = counts - pad
-    starts = jnp.cumsum(counts) - counts
-    # excluded records sort first (key < 0): shift starts past them
-    starts = starts + pad[0]
-    return sv, counts, starts, sk
+    counts = jnp.bincount(jnp.where(keys < 0, n_groups, keys),
+                          length=n_groups + 1)
+    # excluded records (key < 0) are counted in the extra last bin; they
+    # sort first, so the starts shift past them
+    starts = jnp.cumsum(counts[:-1]) - counts[:-1] + counts[-1]
+    return sv, counts[:-1], starts, sk
 
 
 def segment_median(keys: jax.Array, vals: jax.Array, n_groups: int
@@ -384,7 +425,7 @@ def segment_median(keys: jax.Array, vals: jax.Array, n_groups: int
     lo = jnp.clip(s + jnp.maximum((c - 1) // 2, 0), 0, sv.shape[0] - 1)
     hi = jnp.clip(s + jnp.maximum(c // 2, 0), 0, sv.shape[0] - 1)
     med = (sv[lo] + sv[hi]) * 0.5
-    return jnp.where(c > 0, med, jnp.nan), counts
+    return jnp.where(c > 0, med, jnp.nan), counts.astype(jnp.float32)
 
 
 def segment_quantile(keys: jax.Array, vals: jax.Array, n_groups: int,
@@ -410,7 +451,7 @@ def segment_quantile(keys: jax.Array, vals: jax.Array, n_groups: int,
     hi = jnp.clip(s + jnp.minimum(base + 1, jnp.maximum(c - 1, 0)),
                   0, sv.shape[0] - 1)
     q = sv[lo] + (sv[hi] - sv[lo]) * frac
-    return jnp.where(c > 0, q, jnp.nan), counts
+    return jnp.where(c > 0, q, jnp.nan), counts.astype(jnp.float32)
 
 
 def segment_distinct(keys: jax.Array, vals: jax.Array, n_groups: int
@@ -432,7 +473,7 @@ def segment_distinct(keys: jax.Array, vals: jax.Array, n_groups: int
     distinct = jax.ops.segment_sum(
         jnp.where(new, 1.0, 0.0), jnp.clip(sk, 0, n_groups - 1),
         num_segments=n_groups)
-    return distinct, counts
+    return distinct, counts.astype(jnp.float32)
 
 
 def segment_order_stat(table: Table, keys: jax.Array, n_groups: int,
@@ -480,72 +521,70 @@ def finalize_stacked(aggs: Mapping[str, Tuple[str, str]], src: list,
     return out
 
 
-def _group_aggregate_kernel(table: Table, key: str, n_groups: int,
-                            aggs: Mapping[str, Tuple[str, str]], *,
-                            mode: Optional[str], layout: Optional[str],
-                            n_partitions: int,
-                            capacity_factor: float) -> Dict[str, jax.Array]:
-    """Tuned plan: all distributive aggregates fused into one kernel sweep."""
-    keys, vals, src = stacked_columns(table, key, n_groups, aggs)
-    if layout is None:
-        layout = "dense" if n_groups <= DENSE_GROUP_LIMIT else "partitioned"
-    sums, overflow = stacked_group_sums(
-        keys, vals, n_groups, layout=layout, mode=mode,
-        n_partitions=n_partitions, capacity_factor=capacity_factor)
-    out = finalize_stacked(
-        aggs, src, sums,
-        lambda op, col: segment_order_stat(table, keys, n_groups, op, col))
-    out["_overflow"] = overflow.astype(jnp.int32)
-    return out
-
-
 def _fused_dense(keys: jax.Array, vals: jax.Array, n_groups: int, *,
-                 mode: Optional[str], block: int = 512) -> jax.Array:
+                 mode: Optional[str], block: int = 1024) -> jax.Array:
     """Small key domain: positional chunking, full-width tables, no sort.
 
-    Rows are split into chunks by position; each chunk's (n_bins, C) table
+    Rows are split into chunks by position; each chunk's (C, n_bins) table
     covers every group, so the result is the exact sum of chunk tables —
     no partitioning pass, no overflow possible. Padding rows carry zero
-    values, so their bin placement is irrelevant."""
+    values, so their bin placement is irrelevant. A chunk holds fewer than
+    F32_EXACT_COUNT rows, so its counts are exact and add up in int32."""
     N, C = vals.shape
     bins = max(128, -(-n_groups // 128) * 128)
-    n_chunks = 8 if N >= 8 * block else 1
+    n_chunks = max(8, -(-N // (F32_EXACT_COUNT - block))) \
+        if N >= 8 * block else 1
     per_chunk = -(-N // n_chunks)
     t = -(-per_chunk // block) * block
     pad = n_chunks * t - N
     k = jnp.pad(keys, (0, pad))
-    v = jnp.pad(vals, ((0, pad), (0, 0)))
+    v = jnp.pad(vals.T, ((0, 0), (0, pad)))          # measure-major (C, N)
     table = hash_aggregate_multi(k.reshape(n_chunks, t),
-                                 v.reshape(n_chunks, t, C),
+                                 v.reshape(C, n_chunks, t).swapaxes(0, 1),
                                  n_bins=bins, block=block, mode=mode)
-    return table.sum(axis=0)[:n_groups]
+    counts = table[:, 0].astype(jnp.int32).sum(axis=0).astype(jnp.float32)
+    return table.sum(axis=0).at[0].set(counts).T[:n_groups]
+
+
+def partition_layout(n_rows: int, n_groups: int, n_partitions: int,
+                     capacity_factor: float, block: int
+                     ) -> Tuple[int, int, int, int]:
+    """(partitions, groups per partition, table bins, slots per partition)
+    of the range-partitioned aggregate. Partitions: ``n_partitions``, or as
+    many as keep each partition table within MAX_PARTITION_BINS slots and
+    each partition's slots below F32_EXACT_COUNT (a group's count stays
+    exact in the kernel's f32 table)."""
+    P = max(n_partitions, -(-n_groups // MAX_PARTITION_BINS),
+            -(-int(n_rows * capacity_factor) // (F32_EXACT_COUNT // 2)))
+    range_size = -(-n_groups // P)
+    bins = max(128, -(-range_size // 128) * 128)
+    return P, range_size, bins, _capacity(n_rows, P, capacity_factor, block)
 
 
 def _fused_partitioned(keys: jax.Array, vals: jax.Array, n_groups: int, *,
                        mode: Optional[str], n_partitions: int,
-                       capacity_factor: float, block: int = 256
+                       capacity_factor: float, block: int = 1024
                        ) -> Tuple[jax.Array, jax.Array]:
     """Large key domain: range partition, then fused per-partition tables.
 
     Range partitioning on the (clipped, dense) group ids makes the
     partition-local slot (key % range_size) collision-free, so the kernel
     result is EXACT whenever no partition overflows its capacity; overflow
-    is counted and returned, as in aggregate.count_partitioned."""
+    is counted and returned, as in aggregate.count_partitioned. The
+    layout's sizes come from ``partition_layout``."""
     N, C = vals.shape
-    range_size = -(-n_groups // n_partitions)
-    bins = max(128, -(-range_size // 128) * 128)
+    n_partitions, range_size, bins, pad_t = partition_layout(
+        N, n_groups, n_partitions, capacity_factor, block)
     part = jnp.clip(keys // range_size, 0, n_partitions - 1)
     order = jnp.argsort(part, stable=True)
-    sk, sv = keys[order], vals[order]
     counts_p = jnp.bincount(part, length=n_partitions)
     starts = jnp.cumsum(counts_p) - counts_p
-    pad_t = int(max(block,
-                    -(-int(N // n_partitions * capacity_factor) // block)
-                    * block))
-    pk, pv, overflow = pad_partitions(sk, sv, starts, counts_p, n_partitions,
-                                      pad_t)
+    # measure columns travel one by one and are stacked measure-major
+    pk, pv, overflow = pad_partitions(
+        keys[order], [vals[:, c][order] for c in range(C)], starts,
+        counts_p, n_partitions, pad_t)
     local = jnp.where(pk < 0, 0, pk % range_size)   # padded vals are zero
-    table = hash_aggregate_multi(local, pv, n_bins=bins, block=block,
-                                 mode=mode)
-    flat = table[:, :range_size, :].reshape(n_partitions * range_size, C)
-    return flat[:n_groups], overflow
+    table = hash_aggregate_multi(local, jnp.stack(pv, axis=1), n_bins=bins,
+                                 block=block, mode=mode)
+    flat = table[:, :, :range_size].transpose(0, 2, 1)
+    return flat.reshape(n_partitions * range_size, C)[:n_groups], overflow

@@ -42,7 +42,6 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analytics.columnar import (concat_slices, segment_distinct,
@@ -501,9 +500,9 @@ def dist_count(mesh: Mesh, policy: PlacementPolicy, cardinality: int, *,
         ("count",))
     ctx = planner.ExecutionContext(executor="xla", mesh=mesh, policy=policy,
                                    axis=axis, capacity_factor=capacity_factor)
-    rebalance = shard_map(
+    rebalance = jax.shard_map(
         lambda t: _rebalance_to_interleave(t, n, axis), mesh=mesh,
-        in_specs=P(), out_specs=P(), check_rep=False)
+        in_specs=P(), out_specs=P(), check_vma=False)
 
     def fn(keys):
         counts = planner.execute_plan(lplan, {"keys": {"k": keys}},

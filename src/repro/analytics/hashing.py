@@ -41,15 +41,15 @@ def pad_partitions(sorted_keys: jax.Array, sorted_vals: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Dense (P, padT) layout from partition-contiguous arrays.
 
-    ``sorted_vals`` may carry trailing measure dims — (N,) or (N, C) — so a
-    stacked multi-aggregate matrix rides through the same gather as its keys.
-    Returns (keys (P, padT), vals (P, padT[, C]), overflow: total records
-    beyond capacity). Padded slots carry ``pad_key`` and zero values."""
+    ``sorted_vals`` is one (N,) payload column or a list of them (the
+    measure columns of a multi-aggregate ride through the same gather as
+    their keys). Returns (keys (P, padT), vals (P, padT) or a list of them,
+    overflow: total records beyond capacity). Padded slots carry
+    ``pad_key`` and zero values."""
     idx = starts[:, None] + jnp.arange(pad_t)[None, :]          # (P, padT)
     valid = jnp.arange(pad_t)[None, :] < jnp.minimum(counts, pad_t)[:, None]
     idx = jnp.clip(idx, 0, sorted_keys.shape[0] - 1)
     keys = jnp.where(valid, sorted_keys[idx], pad_key)
-    vmask = valid.reshape(valid.shape + (1,) * (sorted_vals.ndim - 1))
-    vals = jnp.where(vmask, sorted_vals[idx], 0)
+    vals = jax.tree.map(lambda v: jnp.where(valid, v[idx], 0), sorted_vals)
     overflow = jnp.maximum(counts - pad_t, 0).sum()
     return keys, vals, overflow

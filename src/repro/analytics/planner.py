@@ -83,14 +83,14 @@ from typing import Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analytics import physical as PH
 from repro.analytics import plan as L
 from repro.analytics import telemetry
 from repro.analytics import tracing
-from repro.analytics.columnar import (DENSE_GROUP_LIMIT, Table,
+from repro.analytics.columnar import (DENSE_GROUP_LIMIT,
+                                      KERNEL_JOIN_MAX_ROWS, Table,
                                       finalize_stacked, group_aggregate,
                                       pkfk_join, pkfk_join_kernel,
                                       segment_distinct, segment_median,
@@ -361,11 +361,13 @@ def choose_join(n_probe: int, n_build: int, ctx: ExecutionContext) -> str:
     The broadcast-compare probe only beats the gather when the MXU actually
     executes it — its reference lowering is an O(n_probe * n_build / P)
     compare — so the cost rule requires a compiled Pallas backend plus a
-    probe side large enough to amortize the partitioning pass."""
+    probe side large enough to amortize the partitioning pass, and both
+    sides below the kernel's KERNEL_JOIN_MAX_ROWS."""
     if ctx.join is not None:
         return ctx.join
     if (kernel_mode(ctx.mode) == "pallas" and ctx.executor != "xla"
-            and n_probe >= (1 << 14) and n_build >= 512):
+            and n_probe >= (1 << 14) and n_build >= 512
+            and max(n_probe, n_build) < KERNEL_JOIN_MAX_ROWS):
         return "kernel"
     return "sorted"
 
@@ -1786,8 +1788,8 @@ def _run_distributed(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
         return ex.execute(phys)
 
     specs = jax.tree_util.tree_map(lambda _: P(axis), padded)
-    return shard_map(local_fn, mesh=mesh, in_specs=(specs,), out_specs=P(),
-                     check_rep=False)(padded)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(specs,),
+                         out_specs=P(), check_vma=False)(padded)
 
 
 def _run_plan(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
@@ -1845,11 +1847,21 @@ class CompiledPlan:
             key=hash(self.cache_key), recorded=self.record)
         return out
 
+    def _indexes(self, tables) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+        if self.ctx.mesh is not None:
+            return {}
+        return {f"{t}.{c}": _INDEX_POOL.get(t, c, tables[t][c])
+                for t, c in self.index_specs}
+
+    def lower(self, tables):
+        """The executable's lowering for ``tables``, join indexes built as a
+        dispatch builds them: ``.compile()`` on it compiles the plan ahead
+        of its first run (into the persistent compile cache, where that
+        is on)."""
+        return self.fn.lower(tables, self._indexes(tables))
+
     def _execute(self, tables) -> Dict[str, jax.Array]:
-        indexes = {}
-        if self.ctx.mesh is None:
-            for t, c in self.index_specs:
-                indexes[f"{t}.{c}"] = _INDEX_POOL.get(t, c, tables[t][c])
+        indexes = self._indexes(tables)
         if not self.record:
             return self.fn(tables, indexes)
         t0 = time.perf_counter()

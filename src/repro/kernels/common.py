@@ -1,16 +1,15 @@
 """Kernel dispatch: TPU Pallas kernel | interpret mode | pure-jnp reference.
 
-The container is CPU-only, so the policy is:
-  * mode="auto":      Pallas on a TPU backend, reference everywhere else
-                      (the dry-run lowers the reference path — its chunked
-                      formulations are shaped to match the kernels' working
-                      sets so memory analysis stays honest).
-  * mode="interpret": run the actual kernel body in the Pallas interpreter
-                      (used by the kernel test suites on CPU).
+  * mode="auto":      compiled Pallas where JAX's default backend is a TPU
+                      (the chip path), the jnp reference everywhere else.
+  * mode="pallas":    force compiled Pallas (needs a TPU, or a described
+                      TPU topology for compile-only checks).
+  * mode="interpret": run the kernel body in the Pallas interpreter — how
+                      the CPU test suites check the kernels' results.
   * mode="ref":       force the pure-jnp oracle.
-  * mode="pallas":    force compiled Pallas (TPU only).
 
 Set globally via env REPRO_KERNEL_MODE or per-call with the ``mode`` kwarg.
+On a TPU nothing falls back: "auto" resolves to "pallas" there.
 """
 from __future__ import annotations
 

@@ -11,11 +11,11 @@ from repro.kernels.hash_aggregate.ref import hash_aggregate_multi_ref
 
 
 def hash_aggregate_multi(ids: jax.Array, vals: jax.Array, *, n_bins: int,
-                         block: int = 512,
+                         block: int = 1024,
                          mode: Optional[str] = None) -> jax.Array:
     """Fused partition-local segment sums over C stacked measure columns.
 
-    ids: (P, T); vals: (P, T, C) -> (P, n_bins, C). The one-hot/ids stream
+    ids: (P, T); vals: (P, C, T) -> (P, C, n_bins). The one-hot/ids stream
     cost is paid once for all C aggregates (see kernel.py)."""
     resolved = kernel_mode(mode)
     if resolved == "pallas":
@@ -28,9 +28,9 @@ def hash_aggregate_multi(ids: jax.Array, vals: jax.Array, *, n_bins: int,
 
 
 def hash_aggregate(ids: jax.Array, vals: jax.Array, *, n_bins: int,
-                   block: int = 512, mode: Optional[str] = None) -> jax.Array:
+                   block: int = 1024, mode: Optional[str] = None) -> jax.Array:
     """Partition-local segment sums. ids, vals: (P, T) -> (P, n_bins).
 
     Thin single-aggregate wrapper over :func:`hash_aggregate_multi`."""
-    return hash_aggregate_multi(ids, vals[..., None], n_bins=n_bins,
-                                block=block, mode=mode)[..., 0]
+    return hash_aggregate_multi(ids, vals[:, None], n_bins=n_bins,
+                                block=block, mode=mode)[:, 0]

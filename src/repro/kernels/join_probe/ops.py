@@ -11,7 +11,7 @@ from repro.kernels.join_probe.ref import join_probe_ref
 
 
 def join_probe(build_keys: jax.Array, build_vals: jax.Array,
-               probe_keys: jax.Array, *, block_p: int = 1024,
+               probe_keys: jax.Array, *, block_p: int = 2048,
                mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """PK-FK partition-local probe -> (matched vals (P,Pk), found (P,Pk))."""
     resolved = kernel_mode(mode)

@@ -43,10 +43,9 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sout_ref, s_scr, *,
         y = jax.lax.dot_general(ri, S, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         y = y + bonus * vi                              # (1, N)
-        # index the leading block dim with a size-1 dslice, not a bare int:
-        # int indices crash the interpreter's store discharge on this jax
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(i, 1), slice(None)),
-                 y[None].astype(o_ref.dtype))
+        # index the leading block dim with a size-1 slice, not a bare int:
+        # int indices crash the interpreter's store discharge
+        o_ref[pl.ds(0, 1), pl.ds(i, 1), :] = y[None].astype(o_ref.dtype)
         S = wi.reshape(-1, 1) * S + ki.reshape(-1, 1) * vi
         return S
 

@@ -20,7 +20,7 @@ from repro.core.topology import TorusTopology
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_layout_mesh(*, multi_pod: bool = False,
@@ -45,4 +45,11 @@ def make_host_mesh(n_data: Optional[int] = None, n_model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     n_data = n_data or (n // n_model)
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """Mesh with Auto axes: the LM steps place state through jit shardings
+    (GSPMD), while jax.make_mesh defaults to Explicit axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -138,7 +138,6 @@ def moe_forward_sharded(p: Dict[str, Any], x: jax.Array, arch: ArchConfig, *,
     over data, sequence over model — the SP layout), so each token lives on
     exactly one device. ``token_spec`` is that PartitionSpec.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = arch.moe
@@ -215,9 +214,9 @@ def moe_forward_sharded(p: Dict[str, Any], x: jax.Array, arch: ArchConfig, *,
         return out.reshape(Bl, Sl, d), aux
 
     espec = P(axis)
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), espec, espec, espec, token_spec),
         out_specs=(token_spec, P()),
-        check_rep=False)
+        check_vma=False)
     return wrapped(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)

@@ -19,7 +19,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.config import RunConfig
@@ -57,11 +56,11 @@ def make_dp_train_step(model: LMModel, cfg: RunConfig, mesh: Mesh, *,
     batch_spec = jax.tree.map(lambda _: P(axis), {"tokens": 0, "labels": 0})
 
     def step(params, opt_state, errors, batch, step_idx):
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             sharded_part, mesh=mesh,
             in_specs=(P(), P(), {k: P(axis) for k in batch}),
             out_specs=(P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
         loss, grads, new_errors = wrapped(params, errors, batch)
         if not compress:
             # pmean already averaged; compression path averages internally

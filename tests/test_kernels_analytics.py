@@ -115,7 +115,7 @@ def test_hash_aggregate_interpret(rng, P, T, bins, block):
 def test_hash_aggregate_multi_interpret(rng, P, T, bins, C, block):
     """Fused multi-aggregate kernel vs oracle, incl. the C=1 edge."""
     ids = jnp.asarray(rng.randint(0, bins, (P, T)), jnp.int32)
-    vals = jnp.asarray(rng.randn(P, T, C), jnp.float32)
+    vals = jnp.asarray(rng.randn(P, C, T), jnp.float32)
     ref = hash_aggregate_multi_ref(ids, vals, n_bins=bins)
     got = hash_aggregate_multi(ids, vals, n_bins=bins, block=block,
                                mode="interpret")
@@ -126,13 +126,13 @@ def test_hash_aggregate_multi_matches_stacked_singles(rng):
     """The fused sweep equals C independent single-aggregate sweeps."""
     P, T, bins, C, block = 2, 768, 128, 4, 256
     ids = jnp.asarray(rng.randint(0, bins, (P, T)), jnp.int32)
-    vals = jnp.asarray(rng.randn(P, T, C), jnp.float32)
+    vals = jnp.asarray(rng.randn(P, C, T), jnp.float32)
     fused = hash_aggregate_multi(ids, vals, n_bins=bins, block=block,
                                  mode="interpret")
     for c in range(C):
-        single = hash_aggregate(ids, vals[..., c], n_bins=bins, block=block,
+        single = hash_aggregate(ids, vals[:, c], n_bins=bins, block=block,
                                 mode="interpret")
-        np.testing.assert_allclose(np.asarray(fused[..., c]),
+        np.testing.assert_allclose(np.asarray(fused[:, c]),
                                    np.asarray(single), atol=1e-4)
 
 

@@ -56,7 +56,10 @@ from repro.optim import adamw
 from repro.runtime.train_loop import make_train_step
 from repro.data.pipeline import synth_batch
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# the train step places its state through jit shardings (GSPMD), so the
+# mesh axes are Auto; jax.make_mesh defaults to Explicit axes
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 arch = REDUCED["qwen3-1.7b"]
 cfg = RunConfig(arch=arch, shape=LM_SHAPES["train_4k"],
                 train=TrainConfig(learning_rate=1e-3, warmup_steps=1))

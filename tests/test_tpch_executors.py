@@ -56,6 +56,24 @@ def test_group_aggregate_kernel_matches_xla_all_ops(rng):
                                        err_msg=f"G={n_groups}/{k}")
 
 
+@pytest.mark.parametrize("executor", ["xla", "kernel"])
+def test_group_aggregate_past_f32_exact_count(executor):
+    """A group of more than 2^24 rows: an f32 scatter-add stops counting
+    at 2^24, so the count must come back as the exact count rounded once
+    to f32, the sum accurate, and the median at the right position."""
+    n = (1 << 24) + 3                      # odd: not an f32 integer
+    v = np.arange(n, dtype=np.int32) % 7
+    t = Table({"k": jnp.zeros(n, jnp.int32),
+               "v": jnp.asarray(v, jnp.float32)})
+    got = group_aggregate(t, "k", 2, {"c": ("count", "v"), "s": ("sum", "v"),
+                                      "m": ("median", "v")},
+                          executor=executor, mode="ref")
+    assert np.asarray(got["c"])[0] == np.float32(n)
+    assert np.asarray(got["_count"])[1] == 0
+    np.testing.assert_allclose(np.asarray(got["s"])[0], v.sum(), rtol=1e-6)
+    assert np.asarray(got["m"])[0] == np.median(v)
+
+
 def test_group_aggregate_kernel_counts_overflow(rng):
     """Skewed keys beyond partition capacity are counted, never dropped."""
     n, n_groups = 20_000, 6000
