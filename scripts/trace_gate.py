@@ -3,7 +3,7 @@
 
 Serves a traced multi-tenant chaos round (fig_service_faults style:
 scheduled build failure + wait poison + mid-round pool kill, morsel-split
-over two pools with stealing), plus one traced whole-plan compile+execute
+over two pools with stealing), plus one traced whole-plan lower+dispatch
 for the plan-level spans, and exits non-zero if any contract is broken:
 
   1. the exported Chrome trace is valid JSON with >= 6 distinct phase
@@ -14,14 +14,16 @@ for the plan-level spans, and exits non-zero if any contract is broken:
      ServiceStats reports a populated per-class p99 decomposition;
   4. every injected fault produced a NON-EMPTY flight-recorder dump;
   5. zero-cost-when-disabled: an identical untraced round allocates NO
-     spans (``Tracer.created`` unchanged), and flipping the tracing flag
-     does not change the plan-cache key (no re-jit).
+     spans (``Tracer.created`` unchanged) and leaves no garbage-collector
+     hook registered, and flipping the tracing flag does not change the
+     plan-cache key (no re-jit).
 
 The script configures its own fake host devices, so it must run as a
 standalone process (scripts/ci.sh invokes it after drift_gate):
 
     PYTHONPATH=src python scripts/trace_gate.py
 """
+import gc
 import json
 import os
 import sys
@@ -78,7 +80,7 @@ def main() -> int:
                                   poison_wait_at={8}, kill_pool_at=(11, 1))
     with tracing.tracing() as tr:
         rids, results, st = serve_round(faults)
-        # whole-plan compile+execute for the plan-level spans (the
+        # whole-plan lower+dispatch for the plan-level spans (the
         # morsel-split service path never dispatches a whole CompiledPlan);
         # the cache is cleared so the compile is a genuine miss
         q6 = LOGICAL_QUERIES["q6"]
@@ -118,9 +120,9 @@ def main() -> int:
         print("trace_gate: FAIL — no pool/worker lanes in the trace "
               f"(lanes: {trace.lanes()})")
         return 1
-    needed = {"queue.wait", "dispatch.build", "morsel.run",
+    needed = {"queue.wait", "serve.round", "dispatch.build", "morsel.run",
               "merge.partials", "result.deliver", "retry.backoff",
-              "plan.compile", "plan.execute"}
+              "plan.lower", "plan.dispatch", "plan.device_wait"}
     missing = needed - names
     if missing:
         print(f"trace_gate: FAIL — serving-path phases missing from the "
@@ -186,6 +188,11 @@ def main() -> int:
         print(f"trace_gate: FAIL — untraced round allocated "
               f"{after - before} spans; a hot-path hook is missing its "
               "tracing_enabled() guard")
+        return 1
+    if any(getattr(cb, "__module__", "") == tracing.__name__
+           for cb in gc.callbacks):
+        print("trace_gate: FAIL — the tracer's gc hook is still "
+              "registered with tracing off")
         return 1
     off_key = planner.compile_plan(q6, tables, ctx).cache_key
     tracing.enable_tracing()

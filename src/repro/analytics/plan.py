@@ -49,7 +49,7 @@ predicates.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Mapping, Optional, Tuple, Union
 
 
@@ -234,9 +234,13 @@ Node = Union[Scan, Filter, Project, Join, Aggregate, TopK, Attach]
 
 @dataclass(frozen=True)
 class LogicalPlan:
-    """A root node plus the result keys to emit (None = everything)."""
+    """A root node plus the result keys to emit (None = everything).
+    ``name`` labels the plan in traces and profiles (its executable is
+    ``jit_plan_<name>``); it is no part of the plan's identity, so two
+    plans that differ only in name share one plan-cache entry."""
     root: Node
     outputs: Optional[Tuple[str, ...]] = None
+    name: str = field(default="", compare=False)
 
 
 def scan(table: str) -> Scan:

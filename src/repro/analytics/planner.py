@@ -1232,11 +1232,20 @@ class _LocalExecutor:
         self.record = record
         self.stats: Dict[int, Dict[str, jax.Array]] = {}
         self._ids: Dict[PH.PNode, int] = {}
+        # name scope of each node, ``<NodeType>_<preorder index>``: the
+        # ops a node lowers to carry it in their metadata (a profile's
+        # per-op name path)
+        self._scopes: Dict[PH.PNode, str] = {}
 
     def run(self, node: PH.PNode):
         hit = self._memo.get(node)
         if hit is None:
-            hit = self._eval(node)
+            scope = self._scopes.get(node)
+            if scope is None:
+                hit = self._eval(node)
+            else:
+                with jax.named_scope(scope):
+                    hit = self._eval(node)
             self._memo[node] = hit
         return hit
 
@@ -1378,6 +1387,8 @@ class _LocalExecutor:
 
     # -- plan root ----------------------------------------------------------
     def execute(self, phys: PH.PhysicalPlan) -> Dict[str, jax.Array]:
+        for i, n in enumerate(PH.walk(phys.root)):
+            self._scopes.setdefault(n, f"{type(n).__name__}_{i}")
         if self.record:
             # node id = walk_unique enumerate order: deterministic for a
             # fixed tree, shared with the StatsRegistry's accounting
@@ -1799,6 +1810,18 @@ def _run_plan(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
     return _run_distributed(phys, ctx, profile, record, tables, indexes)
 
 
+def _jit_plan(plan: L.LogicalPlan, phys: PH.PhysicalPlan,
+              ctx: ExecutionContext, profile, record: bool):
+    """The plan's executable, as a function named ``plan_<name>``: its
+    XLA module reads ``jit_plan_<name>`` in a profile (a bare partial
+    would read ``jit__unknown``)."""
+    def run(tables, indexes):
+        return _run_plan(phys, ctx, profile, record, tables, indexes)
+    run.__name__ = run.__qualname__ = (f"plan_{plan.name}" if plan.name
+                                       else "plan")
+    return jax.jit(run)
+
+
 class CompiledPlan:
     """Re-entrant dispatch handle for one (plan, context, shape signature).
 
@@ -1835,16 +1858,21 @@ class CompiledPlan:
 
     def __call__(self, tables) -> Dict[str, jax.Array]:
         # the tracing flag is read HERE, per dispatch — it is deliberately
-        # NOT part of the plan-cache key: plan.execute is a host-side span
+        # NOT part of the plan-cache key: plan.dispatch is a host-side span
         # around an unchanged executable, so flipping it must never re-jit
-        # (only telemetry's ``record`` adds traced operations)
+        # (only telemetry's ``record`` adds traced operations). The span
+        # ends when the call returns: the index lookups and the enqueue,
+        # not the device's work (the caller's block_until_ready waits
+        # for that); the request is the one the calling thread works for
         if not tracing.tracing_enabled():
             return self._execute(tables)
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         out = self._execute(tables)
         tracing.tracer().add_complete(
-            "plan.execute", "plan", t0, time.monotonic(), pid="plan",
-            key=hash(self.cache_key), recorded=self.record)
+            "plan.dispatch", "plan", t0, time.perf_counter(),
+            trace_id=tracing.current_trace_id(), pid="plan",
+            plan=self.plan.name, key=hash(self.cache_key),
+            recorded=self.record)
         return out
 
     def _indexes(self, tables) -> Dict[str, Tuple[jax.Array, jax.Array]]:
@@ -1897,19 +1925,18 @@ def compile_plan(plan: L.LogicalPlan, tables,
     entry = _PLAN_CACHE.get(key)
     if entry is None:
         traced = tracing.tracing_enabled()
-        t0 = time.monotonic() if traced else 0.0
+        t0 = time.perf_counter() if traced else 0.0
         L.validate(plan)     # fail fast (and once) instead of mid-trace
         phys = lower(plan, ctx, _true_rows(tables), profile)
-        fn = jax.jit(functools.partial(_run_plan, phys, ctx, profile,
-                                       record))
+        fn = _jit_plan(plan, phys, ctx, profile, record)
         entry = (phys, fn)
         _PLAN_CACHE.put(key, entry)
         if traced:
-            # compile vs execute split per plan-cache key: this span is
-            # the lowering + jit construction a cache hit amortizes away
+            # lowering + jit construction, which a cache hit amortizes
+            # away; XLA compiles at the first call (or ``lower().compile()``)
             tracing.tracer().add_complete(
-                "plan.compile", "plan", t0, time.monotonic(), pid="plan",
-                key=hash(key))
+                "plan.lower", "plan", t0, time.perf_counter(), pid="plan",
+                plan=plan.name, key=hash(key))
     elif record:
         entry = _maybe_replan(key, entry, plan, ctx, profile, tables)
     phys, fn = entry
@@ -1933,7 +1960,7 @@ def _maybe_replan(key, entry, plan, ctx, profile, tables):
                  observed=reg.observed_joins(key))
     if phys == entry[0]:
         return entry
-    fn = jax.jit(functools.partial(_run_plan, phys, ctx, profile, True))
+    fn = _jit_plan(plan, phys, ctx, profile, True)
     entry = (phys, fn)
     _PLAN_CACHE.put(key, entry)
     reg.note_replanned(key, phys)

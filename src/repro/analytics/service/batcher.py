@@ -95,7 +95,7 @@ class QueryBatcher:
                 planner.table_signature(req.tables))
 
     def group(self, requests: List[QueryRequest]) -> List[QueryBatch]:
-        t0 = time.monotonic() if tracing.tracing_enabled() else 0.0
+        t0 = time.perf_counter() if tracing.tracing_enabled() else 0.0
         groups: Dict[Tuple, QueryBatch] = {}
         for req in requests:
             key = self.batch_key(req)
@@ -113,6 +113,6 @@ class QueryBatcher:
                     self._stats.batched_queries += len(batch.requests)
         if requests and t0 and tracing.tracing_enabled():
             tracing.tracer().add_complete(
-                "batch.group", "batcher", t0, time.monotonic(),
+                "batch.group", "batcher", t0, time.perf_counter(),
                 requests=len(requests), batches=len(groups))
         return list(groups.values())

@@ -123,7 +123,7 @@ class QueryTask:
         self.result: Optional[Dict[str, jax.Array]] = None
         self.submit_t: float = 0.0          # scheduler.submit stamp
         self.merge_t: float = 0.0           # last morsel done, merge begins
-        self.done_t: float = 0.0            # completion stamp (monotonic)
+        self.done_t: float = 0.0            # completion stamp (perf_counter)
         self.trace_id: int = -1             # owning request id (service)
         if morsel_fn is None:
             self.morsels = [_Morsel(self, 0, 0, 0)]
@@ -150,28 +150,54 @@ class QueryTask:
         tasks, whose unit is the per-morsel partial executable."""
         return None if self.compiled is None else self.compiled.physical
 
+    def _dispatch(self, m: _Morsel, pool_id: int):
+        """Enqueue one morsel's executable; returns its unready outputs."""
+        if self.morsel_fn is None:
+            if not tracing.tracing_enabled():
+                return self.compiled(self.tables)
+            with tracing.working_for(self.trace_id):
+                return self.compiled(self.tables)
+        # the EXECUTING pool's id, not home_pool: a stolen morsel must
+        # probe against the thief's build replica
+        if not tracing.tracing_enabled():
+            return self.morsel_fn(self.tables, m.lo, length=m.length,
+                                  pool=pool_id)
+        t0 = time.perf_counter()
+        out = self.morsel_fn(self.tables, m.lo, length=m.length,
+                             pool=pool_id)
+        tracing.tracer().add_complete(
+            "plan.dispatch", "plan", t0, time.perf_counter(),
+            trace_id=self.trace_id, pid=f"pool{pool_id}", seq=m.seq)
+        return out
+
+    def _ready(self, out, pool_id: Optional[int] = None):
+        """block_until_ready, spanned as ``plan.device_wait``."""
+        if not tracing.tracing_enabled():
+            return jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(out)
+        tracing.tracer().add_complete(
+            "plan.device_wait", "plan", t0, time.perf_counter(),
+            trace_id=self.trace_id,
+            pid="service" if pool_id is None else f"pool{pool_id}")
+        return out
+
     def _run_morsel(self, m: _Morsel, pool_id: int = 0) -> None:
         try:
             with self._lock:
                 if self._poison is not None:
                     raise self._poison
-            if self.morsel_fn is None:
-                if self.compiled.ctx.mesh is not None:
-                    with _MESH_DISPATCH_LOCK:
-                        out = jax.block_until_ready(
-                            self.compiled(self.tables))
-                else:
-                    out = jax.block_until_ready(self.compiled(self.tables))
-                with self._lock:
-                    self.result = out
+            if self.compiled is not None and \
+                    self.compiled.ctx.mesh is not None:
+                with _MESH_DISPATCH_LOCK:
+                    out = self._ready(self._dispatch(m, pool_id), pool_id)
             else:
-                # the EXECUTING pool's id, not home_pool: a stolen morsel
-                # must probe against the thief's build replica
-                part = jax.block_until_ready(
-                    self.morsel_fn(self.tables, m.lo, length=m.length,
-                                   pool=pool_id))
-                with self._lock:
-                    self._partials[m.seq] = part
+                out = self._ready(self._dispatch(m, pool_id), pool_id)
+            with self._lock:
+                if self.morsel_fn is None:
+                    self.result = out
+                else:
+                    self._partials[m.seq] = out
         except BaseException as e:  # noqa: BLE001 — surfaced to waiter
             with self._lock:
                 self._error = e
@@ -185,20 +211,20 @@ class QueryTask:
     def _finish(self) -> None:
         # the merge phase begins when the LAST morsel lands — everything
         # between merge_t and done_t is morsel-order merge + finalize
-        self.merge_t = time.monotonic()
+        self.merge_t = time.perf_counter()
         if self._error is None and self.morsel_fn is not None:
             try:
                 # merge in MORSEL order, not completion order: the served
                 # result must not depend on which pool finished first
                 sums, ovf = merge_morsel_partials(
                     [self._partials[i] for i in range(len(self.morsels))])
-                self.result = jax.block_until_ready(self.finalize(sums, ovf))
+                self.result = self._ready(self.finalize(sums, ovf))
             except BaseException as e:  # noqa: BLE001
                 self._error = e
         # stamp completion HERE, not when a waiter gets around to joining:
         # per-query latency must not include time spent waiting on other
         # tasks in the drain loop
-        self.done_t = time.monotonic()
+        self.done_t = time.perf_counter()
         if tracing.tracing_enabled() and self.morsel_fn is not None:
             tracing.tracer().add_complete(
                 "merge.partials", "scheduler", self.merge_t, self.done_t,
@@ -226,7 +252,7 @@ class WorkerPool:
     # fault-tolerance state (mutated under the scheduler's condition)
     dead: bool = False            # killed: workers exited, no new work
     quarantined: bool = False     # straggler/hang: avoided by dispatch
-    heartbeat_t: float = 0.0      # last worker take/finish (monotonic)
+    heartbeat_t: float = 0.0      # last worker take/finish (perf_counter)
     inflight: int = 0             # morsels currently executing
     ewma_s: float = 0.0           # EWMA morsel service time (ft.py idiom)
     samples: int = 0
@@ -288,7 +314,7 @@ class MorselScheduler:
         self.hang_after_s = hang_after_s
         shards = jax.device_count() if n_shards is None else n_shards
         per = max(1, shards // n_pools)
-        now = time.monotonic()
+        now = time.perf_counter()
         self.pools = [WorkerPool(i, min(i * per, shards),
                                  min((i + 1) * per, shards) if i < n_pools - 1
                                  else shards, heartbeat_t=now)
@@ -309,7 +335,7 @@ class MorselScheduler:
     def start(self) -> None:
         if self._threads:
             return
-        now = time.monotonic()
+        now = time.perf_counter()
         for pool in self.pools:
             pool.heartbeat_t = now
             for w in range(self._workers_per_pool):
@@ -392,7 +418,7 @@ class MorselScheduler:
                 raise RuntimeError("no live worker pools — every pool is "
                                    "dead or quarantined")
             self._tasks += 1
-            task.submit_t = time.monotonic()
+            task.submit_t = time.perf_counter()
             dense_pool = min(live, key=lambda p: len(p.queue)).pool_id
             # SPARSE stripes a task's morsels across every live pool,
             # starting from a per-task rotating base — otherwise
@@ -467,7 +493,7 @@ class MorselScheduler:
         morsel time > ``straggler_threshold`` x the live-pool median),
         then requeue their backlogs onto survivors. Never quarantines the
         last live pool. Returns newly quarantined pool ids."""
-        now = time.monotonic() if now is None else now
+        now = time.perf_counter() if now is None else now
         newly: List[int] = []
         with self._cv:
             for p in self.pools:
@@ -548,14 +574,14 @@ class MorselScheduler:
                     return
                 pool.executed += 1
                 pool.inflight += 1
-                pool.heartbeat_t = time.monotonic()
+                pool.heartbeat_t = time.perf_counter()
             delay = (self.faults.morsel_delay(pool.pool_id)
                      if self.faults is not None else 0.0)
             if delay > 0.0:
                 time.sleep(delay)
-            t0 = time.monotonic()
+            t0 = time.perf_counter()
             m.task._run_morsel(m, pool.pool_id)
-            t1 = time.monotonic()
+            t1 = time.perf_counter()
             if tracing.tracing_enabled():
                 tracing.tracer().add_complete(
                     "morsel.run", "scheduler", t0, t1,
@@ -564,7 +590,7 @@ class MorselScheduler:
             dt = t1 - t0 + delay                # EWMA must see the straggle
             with self._cv:
                 pool.inflight -= 1
-                pool.heartbeat_t = time.monotonic()
+                pool.heartbeat_t = time.perf_counter()
                 pool.samples += 1
                 pool.ewma_s = (dt if pool.samples == 1
                                else 0.3 * dt + 0.7 * pool.ewma_s)

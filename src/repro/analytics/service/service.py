@@ -289,7 +289,7 @@ class AnalyticsService:
             req_id=rid, plan=plan, tables=tables,
             context=context or ExecutionContext(),
             deadline_s=(None if deadline_s is None
-                        else time.monotonic() + deadline_s),
+                        else time.perf_counter() + deadline_s),
             client_id=client_id, priority=priority)
         if not self.queue.offer(req):
             return None
@@ -307,10 +307,10 @@ class AnalyticsService:
                timeout: Optional[float] = None) -> Optional[QueryResult]:
         """Pop the terminal result for one request, waiting up to
         ``timeout`` seconds (None = forever). Returns None on timeout."""
-        end = None if timeout is None else time.monotonic() + timeout
+        end = None if timeout is None else time.perf_counter() + timeout
         with self._results_cv:
             while req_id not in self._results:
-                remaining = None if end is None else end - time.monotonic()
+                remaining = None if end is None else end - time.perf_counter()
                 if remaining is not None and remaining <= 0:
                     return None
                 self._results_cv.wait(0.05 if remaining is None
@@ -362,6 +362,9 @@ class AnalyticsService:
         window = AdaptiveBatchWindow(self.config.min_batch,
                                      self.config.max_batch)
         while True:
+            # a profiler trace turns the spans on; checked before the
+            # dequeue so that a round is traced as a whole or not at all
+            tracing.follow_profiler()
             self._collect_overload_shed(None)
             # deadline staleness: shed requests that expired while earlier
             # rounds were served, instead of dequeuing them late
@@ -411,14 +414,15 @@ class AnalyticsService:
         a request that expires while an earlier round is being served is
         shed (counted in ``expired``) instead of dispatched late."""
         if self.serving:
-            end = None if timeout is None else time.monotonic() + timeout
+            end = None if timeout is None else time.perf_counter() + timeout
             with self._results_cv:
                 while self._pending:
-                    if end is not None and time.monotonic() >= end:
+                    if end is not None and time.perf_counter() >= end:
                         break
                     self._results_cv.wait(0.05)
             return self.take_results()
         out: Dict[int, QueryResult] = {}
+        tracing.follow_profiler()
         self._busy_enter()
         try:
             self._drain_snapshot(out)
@@ -428,7 +432,7 @@ class AnalyticsService:
         return out
 
     def _busy_enter(self) -> None:
-        t = time.monotonic()
+        t = time.perf_counter()
         with self._lock:
             if self._active_drains == 0:
                 self._busy_start = t
@@ -441,7 +445,7 @@ class AnalyticsService:
                 # busy time is the UNION of active-serving intervals:
                 # overlapping drains must not double-count (qps would
                 # be understated)
-                self._busy_s += time.monotonic() - self._busy_start
+                self._busy_s += time.perf_counter() - self._busy_start
 
     def _drain_snapshot(self, out: Dict[int, QueryResult]) -> None:
         remaining = len(self.queue)
@@ -469,9 +473,24 @@ class AnalyticsService:
     # -- one serving round --------------------------------------------------
     def _serve_round(self, round_reqs: List[QueryRequest],
                      out: Optional[Dict[int, QueryResult]]) -> None:
+        """Serve one dequeued batch to its end. The loop takes no new
+        request until the round's last share is awaited: ``serve.round``
+        spans that time, from the dequeue to the last share."""
+        if not tracing.tracing_enabled():
+            self._serve_shares(round_reqs, out)
+            return
+        t0 = time.perf_counter()
+        shares = self._serve_shares(round_reqs, out)
+        tracing.tracer().add_complete(
+            "serve.round", "service", t0, time.perf_counter(),
+            requests=len(round_reqs), shares=shares)
+
+    def _serve_shares(self, round_reqs: List[QueryRequest],
+                      out: Optional[Dict[int, QueryResult]]) -> int:
+        """Batch, dispatch and await one round; returns its share count."""
         # dispatch-time deadline re-check: take_batch's check can go stale
         # while the batch waits its turn behind other rounds
-        now = time.monotonic()
+        now = time.perf_counter()
         live = []
         for req in round_reqs:
             if req.expired(now):
@@ -479,7 +498,7 @@ class AnalyticsService:
             else:
                 live.append(req)
         if not live:
-            return
+            return 0
         if self.config.batching:
             batches = self.batcher.group(live)
             shares = [s for b in batches for s in b.shares]
@@ -509,6 +528,7 @@ class AnalyticsService:
             # round's other results or poison co-submitted clients
             self._await_share(task, share, attempt, out, build_start,
                               backoff)
+        return len(shares)
 
     def _share_deadline(self, share: List[QueryRequest]) -> Optional[float]:
         """The share keeps trying while ANY member can still benefit."""
@@ -520,7 +540,7 @@ class AnalyticsService:
                    rep: QueryRequest) -> bool:
         policy = self.config.retry
         return (policy is not None
-                and policy.should_retry(attempt, time.monotonic(),
+                and policy.should_retry(attempt, time.perf_counter(),
                                         deadline, key=rep.req_id))
 
     def _count_retry(self, rep: QueryRequest) -> None:
@@ -531,7 +551,7 @@ class AnalyticsService:
     def _try_dispatch(self, rep: QueryRequest):
         """One build+submit attempt -> (task, None) | (None, error str)."""
         traced = tracing.tracing_enabled()
-        t0 = time.monotonic() if traced else 0.0
+        t0 = time.perf_counter() if traced else 0.0
         try:
             task = self.scheduler.build_task(rep.plan, rep.tables,
                                              rep.context)
@@ -542,12 +562,12 @@ class AnalyticsService:
         except Exception as e:  # noqa: BLE001 — reported per share
             if traced:
                 tracing.tracer().add_complete(
-                    "dispatch.build", "service", t0, time.monotonic(),
+                    "dispatch.build", "service", t0, time.perf_counter(),
                     trace_id=rep.req_id, error=type(e).__name__)
             return None, f"{type(e).__name__}: {e}"
         if traced:
             tracing.tracer().add_complete(
-                "dispatch.build", "service", t0, time.monotonic(),
+                "dispatch.build", "service", t0, time.perf_counter(),
                 trace_id=rep.req_id, morsels=len(task.morsels))
         with self._lock:
             self._dispatches += 1
@@ -558,10 +578,10 @@ class AnalyticsService:
         retry_backoff attribution phase) and records the span."""
         delay = self.config.retry.backoff_s(attempt, key=rep.req_id)
         if tracing.tracing_enabled():
-            t0 = time.monotonic()
+            t0 = time.perf_counter()
             time.sleep(delay)
             tracing.tracer().add_complete(
-                "retry.backoff", "service", t0, time.monotonic(),
+                "retry.backoff", "service", t0, time.perf_counter(),
                 trace_id=rep.req_id, attempt=attempt)
         else:
             time.sleep(delay)
@@ -571,13 +591,13 @@ class AnalyticsService:
         """Build+submit with retry/backoff.
 
         Returns (task|None, attempts, err, build_start, backoff_s):
-        ``build_start`` is the monotonic stamp at which THIS share's
+        ``build_start`` is the perf_counter stamp at which THIS share's
         first build attempt began (the end of its batch-wait phase) and
         ``backoff_s`` the backoff slept so far — both feed latency
         attribution."""
         rep = share[0]
         deadline = self._share_deadline(share)
-        build_start = time.monotonic()
+        build_start = time.perf_counter()
         backoff = 0.0
         attempt = 0
         while True:
@@ -633,7 +653,7 @@ class AnalyticsService:
         Returns (value, None, False) on success; (None, err, False) on a
         retryable failure (exception or hang-budget timeout); (None, err,
         True) when the share's deadline passed while waiting."""
-        start = time.monotonic()
+        start = time.perf_counter()
         hang = self.config.hang_timeout_s
         while True:
             try:
@@ -643,7 +663,7 @@ class AnalyticsService:
                 # quarantine + requeue lets the SAME task finish on
                 # surviving pools without burning a retry attempt
                 self.scheduler.check_pools()
-                now = time.monotonic()
+                now = time.perf_counter()
                 if deadline is not None and now > deadline:
                     return None, "deadline exceeded in flight", True
                 if hang is not None and now - start > hang:
@@ -661,7 +681,7 @@ class AnalyticsService:
         # join order (a fast query must not inherit a slow peer's
         # wait-loop position)
         done = (task.done_t if task is not None and task.done_t
-                else time.monotonic())
+                else time.perf_counter())
         for req in share:
             phases = None
             if error is None and value is not None and task is not None \
@@ -700,7 +720,7 @@ class AnalyticsService:
                 phases: Optional[Dict[str, float]] = None) -> None:
         """The single terminal-result sink: stats, SLO, result store."""
         traced = tracing.tracing_enabled()
-        done = time.monotonic() if done is None else done
+        done = time.perf_counter() if done is None else done
         wait = ((req.dispatch_t if req.dispatch_t else done) - req.submit_t)
         res = QueryResult(
             req_id=req.req_id,
@@ -720,7 +740,7 @@ class AnalyticsService:
                     "overload.shed", req=req.req_id, cls=req.priority)
             # delivery lag: task completion -> terminal result visible
             tracing.tracer().add_complete(
-                "result.deliver", "service", done, time.monotonic(),
+                "result.deliver", "service", done, time.perf_counter(),
                 trace_id=req.req_id,
                 outcome=("error" if error is not None else
                          "expired" if expired else
@@ -782,7 +802,7 @@ class AnalyticsService:
                             for p, w in self._class_phases.items()}
             busy = self._busy_s
             if self._active_drains > 0:   # include the in-progress round
-                busy += time.monotonic() - self._busy_start
+                busy += time.perf_counter() - self._busy_start
         per_class: Dict[int, ClassStats] = {}
         for p, c in qs.by_class.items():
             per_class[p] = ClassStats(

@@ -468,18 +468,6 @@ def _annotation(ns: Optional[NodeStats]) -> str:
     return f"[obs {obs}" + (f" | est {est}]" if est else "]")
 
 
-def _time_weight(node: PH.PNode) -> float:
-    """Deterministic relative time weight of one physical node — the cost
-    model's row terms (rows produced + wire rows, movement priced double).
-    Inside a jit the operators fuse, so per-node wall time is NOT
-    observable; the plan-level span's wall is apportioned by these static
-    weights instead, which keeps the rendering golden-snapshotable."""
-    w = float(max(getattr(node, "rows", 0), 0))
-    if isinstance(node, PH.Exchange):
-        w += 2.0 * max(node.moved_rows, 0)
-    return max(w, 1.0)
-
-
 def explain_analyze(plan, tables, ctx=None) -> str:
     """Execute ``plan`` under telemetry and render its physical tree with
     estimated-vs-observed rows per node — ``explain_physical`` made
@@ -487,11 +475,11 @@ def explain_analyze(plan, tables, ctx=None) -> str:
     n_shards); observations are the recorded totals of the run this call
     performed.
 
-    The header carries the dispatch's wall time (the plan-level
-    ``plan.execute`` grain tracing records); each node line carries its
-    deterministic ``t~`` share of it (see ``_time_weight``). Deterministic
-    for fixed tables up to the absolute wall, so golden-snapshotable with
-    the wall normalized."""
+    The header carries the dispatch's wall time. Inside a jit the
+    operators fuse, so no per-node time is shown: a profile attributes
+    device time to nodes through their name scopes (``<NodeType>_<i>``)
+    instead. Deterministic for fixed tables up to the absolute wall, so
+    golden-snapshotable with the wall normalized."""
     from repro.analytics import planner
 
     ctx = ctx or planner.ExecutionContext()
@@ -505,14 +493,9 @@ def explain_analyze(plan, tables, ctx=None) -> str:
         for i, ns in ps.nodes.items():
             by_node[nodes[i]] = ns
     wall = (ps.wall_s[-1] if ps is not None and ps.wall_s else 0.0)
-    uniq = list(PH.walk_unique(compiled.physical.root))
-    total_w = sum(_time_weight(n) for n in uniq) or 1.0
-    pct = {n: 100.0 * _time_weight(n) / total_w for n in uniq}
 
     def annotate(n: PH.PNode) -> str:
-        t = f"[t~{pct.get(n, 0.0):.1f}%]"
-        obs = _annotation(by_node.get(n))
-        return f"{t} {obs}" if obs else t
+        return _annotation(by_node.get(n))
 
     out = PH.describe(compiled.physical, annotate=annotate)
     head, _, rest = out.partition("\n")

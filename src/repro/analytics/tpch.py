@@ -301,7 +301,7 @@ def build_q1(cutoff: int = DATE1 - 90) -> LogicalPlan:
         count_order=("count", "l_quantity"))
     return LogicalPlan(root, ("sum_qty", "sum_base_price", "sum_disc_price",
                               "sum_charge", "avg_qty", "avg_price",
-                              "count_order", "_count", "_overflow"))
+                              "count_order", "_count", "_overflow"), name="q1")
 
 
 def build_q3(segment: int = 1, date: int = DATE1 // 2) -> LogicalPlan:
@@ -314,7 +314,7 @@ def build_q3(segment: int = 1, date: int = DATE1 // 2) -> LogicalPlan:
     agg = li.aggregate("l_orderkey", TableRows("orders"),
                        revenue=("sum", "_rev"))
     return LogicalPlan(agg.top_k("revenue", 10, "o_orderkey"),
-                       ("revenue", "o_orderkey", "_overflow"))
+                       ("revenue", "o_orderkey", "_overflow"), name="q3")
 
 
 def build_q5(region: int = 2, date_lo: int = 0,
@@ -332,7 +332,7 @@ def build_q5(region: int = 2, date_lo: int = 0,
     li = li.filter(col("_s_nation").eq(col("_c_nation")))
     li = li.project(_rev=col("l_extendedprice") * (1 - col("l_discount")))
     root = li.aggregate("_s_nation", N_NATION, revenue=("sum", "_rev"))
-    return LogicalPlan(root, ("revenue", "_count", "_overflow"))
+    return LogicalPlan(root, ("revenue", "_count", "_overflow"), name="q5")
 
 
 def build_q6(date_lo: int = 0, date_hi: int = 365, disc: float = 0.06,
@@ -343,7 +343,7 @@ def build_q6(date_lo: int = 0, date_hi: int = 365, disc: float = 0.06,
     li = scan("lineitem").filter(pred)
     li = li.project(_x=col("l_extendedprice") * col("l_discount"))
     return LogicalPlan(li.aggregate(None, 1, revenue=("sum", "_x")),
-                       ("revenue",))
+                       ("revenue",), name="q6")
 
 
 def build_q18(qty_threshold: float = 212.0) -> LogicalPlan:
@@ -355,7 +355,7 @@ def build_q18(qty_threshold: float = 212.0) -> LogicalPlan:
                     {"_nat": "c_nationkey"})
     root = o.aggregate("o_custkey", TableRows("customer"),
                        qty=("sum", "_qty"))
-    return LogicalPlan(root, ("qty", "_count", "_overflow"))
+    return LogicalPlan(root, ("qty", "_count", "_overflow"), name="q18")
 
 
 def build_qm(cutoff: int = DATE1 - 90) -> LogicalPlan:
@@ -367,7 +367,7 @@ def build_qm(cutoff: int = DATE1 - 90) -> LogicalPlan:
         avg_qty=("avg", "l_quantity"),
         count_order=("count", "l_quantity"))
     return LogicalPlan(root, ("med_qty", "med_price", "avg_qty",
-                              "count_order", "_count", "_overflow"))
+                              "count_order", "_count", "_overflow"), name="qm")
 
 
 def build_qq(cutoff: int = DATE1 - 90) -> LogicalPlan:
@@ -379,7 +379,7 @@ def build_qq(cutoff: int = DATE1 - 90) -> LogicalPlan:
         med_price=("median", "l_extendedprice"),
         count_order=("count", "l_quantity"))
     return LogicalPlan(root, ("p90_price", "p25_qty", "med_price",
-                              "count_order", "_count", "_overflow"))
+                              "count_order", "_count", "_overflow"), name="qq")
 
 
 LOGICAL_QUERIES: Dict[str, LogicalPlan] = {

@@ -4,31 +4,41 @@ PR 7's telemetry answers "where do the ROWS go" (observed Exchange/
 Compact volumes fed back into the cost model); this module answers
 "where does the TIME go". The paper's method is to measure phase-level
 latency before reaching for a mechanism — allocator, placement, load
-balancing — and the serving tier (queue -> batcher -> scheduler ->
-pools) had only scattered ``time.monotonic()`` stamps with no
-request-scoped story. The tracer threads one trace id (the request id,
-or the dispatch id for plan-level work) through every phase:
+balancing. The tracer threads one trace id (the request id, or the
+dispatch id for plan-level work) through every phase:
 
   queue.wait        admission -> dequeue (AdmissionQueue.take_batch)
+  serve.round       one serving round: the dequeued requests' batching,
+                    dispatch and the wait for the round's last share
   batch.group       plan-cache-key grouping + dedup (QueryBatcher)
   dispatch.build    compile_plan + scheduler submit for one share
   retry.backoff     the sleep between failed dispatch attempts
   morsel.run        one morsel on one pool's worker (pid=pool, tid=worker)
   morsel.steal      instant: a pool stole the tail of another's backlog
+  plan.dispatch     one executable call up to its return: join-index
+                    lookups and the enqueue, not the device's work
+  plan.device_wait  block_until_ready on what a plan.dispatch enqueued
   merge.partials    morsel-order partial merge (QueryTask._finish)
   result.deliver    terminal-result fan-out (_record)
-  plan.compile      plan-cache miss: lowering + jit construction
-  plan.execute      one CompiledPlan dispatch (per plan-cache key)
+  plan.lower        plan-cache miss: lowering + jit construction (the
+                    XLA compile happens at the first call)
+  runtime.gc        one Python garbage collection of >= 1 ms; shorter
+                    ones are only counted (``Tracer.gc_short``)
 
-Discipline mirrors ``telemetry.StatsRegistry`` exactly:
+Every stamp is ``time.perf_counter``: the clock the benchmark's window
+uses, and the one a profiler trace is anchored to.
 
-  * one module-level flag (``enable_tracing`` / ``disable_tracing`` /
-    the ``tracing()`` context manager); every instrumentation site is
-    behind ``if tracing_enabled():`` — disabled (the default), the hot
-    path performs ONE module-attribute read and allocates nothing
+Discipline mirrors ``telemetry.StatsRegistry``:
+
+  * one module-level flag; every instrumentation site is behind
+    ``if tracing_enabled():`` — disabled (the default), the hot path
+    performs ONE module-attribute read and allocates nothing
     (``Tracer.created`` counts every span/instant allocated, so the
     zero-overhead contract is assertable, and scripts/trace_gate.py
-    asserts it);
+    asserts it). The flag is on while ``enable_tracing`` / ``tracing()``
+    asks for it, or while a JAX profiler trace runs and the serving loop
+    has called ``follow_profiler()``; the garbage-collector hook is
+    registered only while it is on;
   * the span ring is BOUNDED (``maxlen``) and thread-safe — an
     always-on service cannot grow it without bound;
   * service-level spans are recorded host-side only and the flag is NOT
@@ -48,11 +58,12 @@ Exports:
     WorkerLeakError), so every injected chaos-grid fault yields an
     artifact.
 
-Stdlib-only and leaf-level: planner/service import this module, never
-the reverse.
+Leaf-level: planner/service import this module, never the reverse; JAX
+is imported only to ask whether its profiler is tracing.
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
@@ -64,37 +75,107 @@ from typing import Any, Dict, List, Optional, Tuple
 # ---------------------------------------------------------------------------
 # enable flag (the telemetry.py discipline)
 # ---------------------------------------------------------------------------
-_ENABLED = False
+_ENABLED = False        # what every instrumentation site reads
+_EXPLICIT = False       # enable_tracing / tracing()
+_FOLLOWED = False       # a JAX profiler trace is running (follow_profiler)
 _ENABLE_LOCK = threading.Lock()
+_PROFILER_ACTIVE = None     # jax.profiler.TraceAnnotation.is_enabled
+GC_SPAN_MIN_S = 1e-3        # shorter collections are counted, not spanned
 
 
 def tracing_enabled() -> bool:
     return _ENABLED
 
 
-def enable_tracing() -> None:
+def _apply_locked() -> None:
+    """Recompute the flag; the gc hook is registered exactly while it is
+    on (call under ``_ENABLE_LOCK``)."""
     global _ENABLED
+    on = _EXPLICIT or _FOLLOWED
+    if on and not _ENABLED:
+        gc.callbacks.append(_on_gc)
+    elif not on and _ENABLED:
+        gc.callbacks.remove(_on_gc)
+    _ENABLED = on
+
+
+def enable_tracing() -> None:
+    global _EXPLICIT
     with _ENABLE_LOCK:
-        _ENABLED = True
+        _EXPLICIT = True
+        _apply_locked()
 
 
 def disable_tracing() -> None:
-    global _ENABLED
+    global _EXPLICIT
     with _ENABLE_LOCK:
-        _ENABLED = False
+        _EXPLICIT = False
+        _apply_locked()
 
 
 @contextmanager
 def tracing():
     """Enable tracing for the duration of a block (not reference counted:
     nested blocks share the one global flag)."""
-    prev = _ENABLED
+    prev = _EXPLICIT
     enable_tracing()
     try:
         yield tracer()
     finally:
         if not prev:
             disable_tracing()
+
+
+def follow_profiler() -> bool:
+    """Trace while a JAX profiler trace runs, so that a profile always
+    carries the program's spans; called by the serving loop before each
+    dequeue, which makes a round traced or untraced as a whole. Returns
+    the flag. Costs one call into the profiler when nothing changes."""
+    global _FOLLOWED, _PROFILER_ACTIVE
+    if _PROFILER_ACTIVE is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _PROFILER_ACTIVE = TraceAnnotation.is_enabled
+        except ImportError:
+            _PROFILER_ACTIVE = bool
+    active = bool(_PROFILER_ACTIVE())
+    if active != _FOLLOWED:
+        with _ENABLE_LOCK:
+            _FOLLOWED = active
+            _apply_locked()
+    return _ENABLED
+
+
+_LOCAL = threading.local()
+
+
+@contextmanager
+def working_for(trace_id: int):
+    """Mark the calling thread as working for request ``trace_id``: a span
+    recorded below, by code that cannot know the request (a plan's
+    dispatch), takes it from ``current_trace_id``."""
+    prev = getattr(_LOCAL, "trace_id", -1)
+    _LOCAL.trace_id = trace_id
+    try:
+        yield
+    finally:
+        _LOCAL.trace_id = prev
+
+
+def current_trace_id() -> int:
+    return getattr(_LOCAL, "trace_id", -1)
+
+
+_GC_T0 = [0.0]          # collections are serialized: one start stamp
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    if phase == "start":
+        _GC_T0[0] = time.perf_counter()
+    else:
+        _TRACER.note_gc(_GC_T0[0], time.perf_counter(),
+                        info.get("generation", -1),
+                        info.get("collected", 0))
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +189,7 @@ class Span:
 
     name: str
     cat: str                      # phase family: queue|batch|service|...
-    t0: float                     # time.monotonic seconds
+    t0: float                     # time.perf_counter seconds
     dur: float
     trace_id: int = -1            # request/dispatch id; -1 = unscoped
     span_id: int = -1
@@ -132,7 +213,7 @@ class FlightDump:
     fault tripped, plus whatever the trip site wanted on record."""
 
     reason: str
-    at: float                     # time.monotonic of the trip
+    at: float                     # time.perf_counter of the trip
     args: Dict[str, Any] = field(default_factory=dict)
     spans: List[Span] = field(default_factory=list)
 
@@ -193,12 +274,21 @@ class Tracer:
     ``created`` counts every span/instant ever allocated — the
     zero-overhead-when-disabled guard: a round served with tracing off
     must leave it unchanged.
+
+    ``runtime.gc`` spans come from the garbage collector's callback,
+    which may fire while this thread holds the tracer's lock: they go to
+    a ring of their own, appended without the lock, and ``spans()``
+    merges it in. ``gc_short`` counts the collections too short to span
+    (count, seconds); ``gc_dropped`` that ring's evictions.
     """
 
-    def __init__(self, max_spans: int = 8192, flight_window: int = 128,
-                 max_dumps: int = 64):
+    def __init__(self, max_spans: int = 32768, flight_window: int = 128,
+                 max_dumps: int = 64, max_gc_spans: int = 4096):
         self._lock = threading.Lock()
         self._spans: "deque[Span]" = deque(maxlen=max_spans)
+        self._gc: "deque[Span]" = deque(maxlen=max_gc_spans)
+        self.gc_short = [0, 0.0]
+        self.gc_dropped = 0
         self._open: Dict[int, _OpenSpan] = {}
         self._next_id = 0
         self.flight_window = flight_window
@@ -210,7 +300,7 @@ class Tracer:
     def begin(self, name: str, cat: str, *, trace_id: int = -1,
               parent_id: int = -1, pid: str = "service",
               tid: Optional[str] = None, **args) -> int:
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         tid = tid or threading.current_thread().name
         with self._lock:
             sid = self._next_id
@@ -221,7 +311,7 @@ class Tracer:
         return sid
 
     def end(self, span_id: int, **args) -> Optional[Span]:
-        t1 = time.monotonic()
+        t1 = time.perf_counter()
         with self._lock:
             op = self._open.pop(span_id, None)
             if op is None:
@@ -236,7 +326,7 @@ class Tracer:
                      trace_id: int = -1, parent_id: int = -1,
                      pid: str = "service", tid: Optional[str] = None,
                      **args) -> Span:
-        """Record a retrospective span from existing monotonic stamps."""
+        """Record a retrospective span from existing perf_counter stamps."""
         tid = tid or threading.current_thread().name
         with self._lock:
             sid = self._next_id
@@ -249,7 +339,7 @@ class Tracer:
     def instant(self, name: str, cat: str, *, trace_id: int = -1,
                 pid: str = "service", tid: Optional[str] = None,
                 **args) -> Span:
-        now = time.monotonic()
+        now = time.perf_counter()
         tid = tid or threading.current_thread().name
         with self._lock:
             sid = self._next_id
@@ -258,6 +348,21 @@ class Tracer:
                         tuple(args.items()))
             self._append_locked(span)
         return span
+
+    def note_gc(self, t0: float, t1: float, generation: int,
+                collected: int) -> None:
+        """One finished collection (from the gc callback: takes no
+        lock)."""
+        if t1 - t0 < GC_SPAN_MIN_S:
+            self.gc_short[0] += 1
+            self.gc_short[1] += t1 - t0
+            return
+        if len(self._gc) == self._gc.maxlen:
+            self.gc_dropped += 1
+        self._gc.append(Span("runtime.gc", "runtime", t0, t1 - t0,
+                             pid="runtime", tid="gc",
+                             args=(("generation", generation),
+                                   ("collected", collected))))
 
     def _append_locked(self, span: Span) -> None:
         if len(self._spans) == self._spans.maxlen:
@@ -269,7 +374,7 @@ class Tracer:
     def flight_dump(self, reason: str, **args) -> FlightDump:
         """Snapshot the recent span window (finished ring tail + every
         still-open span, rendered open-ended) as a postmortem artifact."""
-        now = time.monotonic()
+        now = time.perf_counter()
         with self._lock:
             recent = list(self._spans)[-self.flight_window:]
             for op in self._open.values():
@@ -284,7 +389,7 @@ class Tracer:
     # -- lookups ------------------------------------------------------------
     def spans(self) -> List[Span]:
         with self._lock:
-            return list(self._spans)
+            return list(self._spans) + list(self._gc)
 
     def open_spans(self) -> List[_OpenSpan]:
         with self._lock:
@@ -297,6 +402,9 @@ class Tracer:
         with self._lock:
             self._spans.clear()
             self._open.clear()
+            self._gc.clear()
+            self.gc_short = [0, 0.0]
+            self.gc_dropped = 0
             self.dropped = 0
         self.flight.clear()
 

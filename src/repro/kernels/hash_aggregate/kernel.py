@@ -97,6 +97,7 @@ def hash_aggregate_multi_pallas(ids: jax.Array, vals: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((P, C, n_bins), jnp.float32),
         scratch_shapes=[pltpu.VMEM((C, n_bins), jnp.float32)],
         interpret=interpret,
+        name="hash_aggregate",
     )(ids.reshape(P, 8, T // 8), vals.reshape(P, C, 8, T // 8))
 
 

@@ -107,6 +107,7 @@ def join_probe_pallas(build_keys: jax.Array, build_vals: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((Bk, 1), jnp.int32)],
         interpret=interpret,
+        name="join_probe",
     )(build_keys.reshape(P, 1, Bk), build_vals.reshape(P, 1, Bk),
       probe_keys.reshape(P, 8, rows))
     return vals.reshape(P, Pk), found.reshape(P, Pk) > 0

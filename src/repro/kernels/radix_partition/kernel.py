@@ -59,5 +59,6 @@ def block_histograms_pallas(keys: jax.Array, *, n_bins: int, shift: int,
         out_specs=pl.BlockSpec((1, 1, n_bins), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, 1, n_bins), jnp.int32),
         interpret=interpret,
+        name="radix_partition",
     )(keys.reshape(n_blocks, 8, lanes))
     return out.reshape(n_blocks, n_bins)

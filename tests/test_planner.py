@@ -428,3 +428,23 @@ def test_pkfk_join_kernel_residual_pass_exact(rng):
     np.testing.assert_allclose(
         np.asarray(got.col("p")) * np.asarray(got.weights()),
         np.asarray(ref.col("p")) * np.asarray(ref.weights()), rtol=1e-6)
+
+
+def test_plan_name_names_the_executable_not_the_cache_key(data):
+    """A named q3 lowers as ``jit_plan_q3``, its ops under per-node name
+    scopes, and shares its plan-cache entry with the same plan unnamed."""
+    import dataclasses
+    tables = data.as_jax()
+    ctx = ExecutionContext(executor="cost")
+    clear_plan_cache()
+    named = LOGICAL_QUERIES["q3"]
+    assert named.name == "q3"
+    compiled = planner.compile_plan(named, tables, ctx)
+    lowered = compiled.lower(tables)
+    assert lowered.as_text().startswith("module @jit_plan_q3")
+    assert "PTopK_0/PAggregate_1" in lowered.as_text(debug_info=True)
+    h0 = plan_cache_info().hits
+    bare = planner.compile_plan(dataclasses.replace(named, name=""),
+                                tables, ctx)
+    assert bare.cache_key == compiled.cache_key
+    assert plan_cache_info().hits == h0 + 1

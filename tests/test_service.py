@@ -508,7 +508,7 @@ def test_admission_queue_concurrent_conservation():
     take_lock = threading.Lock()
 
     def produce(pid):
-        now = time.monotonic()
+        now = time.perf_counter()
         for i in range(per_producer):
             # ~1/5 requests arrive already expired; priorities cycle
             dl = (now - 1.0) if i % 5 == 0 else None

@@ -10,6 +10,7 @@ collected.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +98,6 @@ def test_q1_kernel_plan_compiles(one_chip):
     plan = planner.compile_plan(tpch.LOGICAL_QUERIES["q1"], tables, ctx)
     hlo = plan.fn.lower(tables, {}).compile().as_text()
     assert "tpu_custom_call" in hlo
+    # named in a profile: the plan's module and the kernel's instruction
+    assert hlo.startswith("HloModule jit_plan_q1")
+    assert re.search(r"%hash_aggregate[.\d]* = .*tpu_custom_call", hlo)

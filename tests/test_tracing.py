@@ -15,6 +15,7 @@ Three layers of coverage:
     request's phase attribution sums to <= its wall latency, and every
     fired fault left a flight-recorder dump.
 """
+import gc
 import json
 import os
 
@@ -180,7 +181,7 @@ def test_disabled_tracing_allocates_nothing(data):
 
 
 def test_tracing_flag_not_in_plan_cache_key(data):
-    """Flipping tracing must hit the same cache entry: plan.execute is a
+    """Flipping tracing must hit the same cache entry: plan.dispatch is a
     host-side span around an unchanged executable (only telemetry's
     ``record`` flag adds traced ops and re-jits)."""
     tables = data.as_jax()
@@ -252,3 +253,99 @@ def test_hammer_span_conservation_under_chaos(data):
         if results[r].value is not None:
             assert "queue.wait" in seen.get(r, set())
             assert "result.deliver" in seen.get(r, set())
+
+
+# ---------------------------------------------------------------------------
+# serving rounds, dispatch vs device wait, the gc hook, the profiler
+# ---------------------------------------------------------------------------
+def _inside(inner, outer) -> bool:
+    return outer.t0 <= inner.t0 and inner.t1 <= outer.t1 + 1e-6
+
+
+def test_serve_round_holds_its_dispatches_and_waits_nest_in_morsels(data):
+    """Whole-plan dispatch: each round's serve.round span contains the
+    dispatch.build of every request it served; each request has a
+    plan.dispatch and a plan.device_wait carrying its id, the wait
+    inside that request's morsel.run."""
+    names = ["q1", "q3", "q6"]
+    with tracing.tracing() as tr:
+        with AnalyticsService(_cfg(morsel_rows=None)) as svc:
+            rids = [submit_query(svc, n, data, context=_ctx())
+                    for n in names]
+            results = svc.drain()
+        spans = tr.spans()
+    assert all(results[r].value is not None for r in rids)
+    rounds = [s for s in spans if s.name == "serve.round"]
+    assert rounds and sum(dict(s.args)["requests"] for s in rounds) == 3
+    for r in rids:
+        mine = {n: [s for s in spans if s.trace_id == r and s.name == n]
+                for n in ("dispatch.build", "plan.dispatch",
+                          "plan.device_wait", "morsel.run", "queue.wait")}
+        assert len(mine["queue.wait"]) == 1
+        assert mine["plan.dispatch"] and mine["plan.device_wait"]
+        (build,) = mine["dispatch.build"]
+        assert any(_inside(build, rd) for rd in rounds)
+        (run,) = mine["morsel.run"]
+        assert all(_inside(w, run) for w in mine["plan.device_wait"])
+        assert all(_inside(d, run) for d in mine["plan.dispatch"])
+        ph = results[r].phases
+        took = sum(s.dur for s in mine["plan.dispatch"]
+                   + mine["plan.device_wait"])
+        assert took <= ph["execute"] + 1e-3
+
+
+def test_untraced_whole_plan_round_allocates_nothing_and_no_gc_hook(data):
+    """The zero-cost contract holds on the whole-plan path too, with the
+    serve.round / plan.dispatch / plan.device_wait sites, and tracing
+    off leaves no garbage-collector hook behind."""
+    before = tracing.tracer().created
+    with AnalyticsService(_cfg(morsel_rows=None)) as svc:
+        rids = [submit_query(svc, n, data, context=_ctx())
+                for n in ("q1", "q3")]
+        results = svc.drain()
+    assert all(results[r].value is not None for r in rids)
+    assert tracing.tracer().created == before
+    assert tracing._on_gc not in gc.callbacks
+
+
+def test_gc_hook_registered_only_while_tracing():
+    assert tracing._on_gc not in gc.callbacks
+    with tracing.tracing() as tr:
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        with tracing.tracing():            # nested: still one hook
+            assert gc.callbacks.count(tracing._on_gc) == 1
+        n0 = tr.gc_short[0] + len([s for s in tr.spans()
+                                   if s.name == "runtime.gc"])
+        gc.collect()
+        n1 = tr.gc_short[0] + len([s for s in tr.spans()
+                                   if s.name == "runtime.gc"])
+        assert n1 >= n0 + 1
+    assert tracing._on_gc not in gc.callbacks
+    assert not tracing.tracing_enabled()
+
+
+def test_long_collections_are_spanned_short_ones_counted():
+    tr = Tracer()
+    tr.note_gc(1.0, 1.0004, 0, 3)
+    tr.note_gc(2.0, 2.0150, 2, 900)
+    assert tr.gc_short[0] == 1 and tr.gc_short[1] == pytest.approx(4e-4)
+    (span,) = tr.spans()
+    assert span.name == "runtime.gc" and span.dur == pytest.approx(0.015)
+    assert dict(span.args) == {"generation": 2, "collected": 900}
+
+
+def test_tracing_follows_the_jax_profiler(tmp_path):
+    import jax
+    assert not tracing.follow_profiler()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert tracing.follow_profiler() and tracing.tracing_enabled()
+        assert tracing._on_gc in gc.callbacks
+    finally:
+        jax.profiler.stop_trace()
+    assert not tracing.follow_profiler()
+    assert tracing._on_gc not in gc.callbacks
+    # an explicit enable outlives the profiler's end
+    with tracing.tracing():
+        assert tracing.follow_profiler()
+    assert not tracing.tracing_enabled()
