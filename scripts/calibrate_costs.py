@@ -251,7 +251,8 @@ def sweep_groups(rows: int, groups_sweep, cols: int, mode,
     wins = []                      # (G, dense_won) in ascending-G order
     for G in sorted(groups_sweep):
         keys = jnp.asarray(rng.randint(0, G, rows).astype(np.int32))
-        vals = jnp.asarray(rng.rand(rows, cols).astype(np.float32))
+        vals = [jnp.asarray(rng.rand(rows).astype(np.float32))
+                for _ in range(cols)]
         t = {}
         for layout in ("dense", "partitioned"):
             fn = jax.jit(functools.partial(stacked_group_sums, n_groups=G,
@@ -280,7 +281,7 @@ def sweep_groups(rows: int, groups_sweep, cols: int, mode,
     # capacity-factor fit: smallest cf with zero overflow on zipf keys
     ds = zipf(rows, max(groups_sweep), seed=3)
     keys = jnp.asarray(ds.keys)
-    vals = jnp.asarray(np.stack([ds.vals] * cols, axis=1))
+    vals = [jnp.asarray(ds.vals)] * cols
     fitted_cf = None
     raw["overflow_at_cf"] = {}
     for cf in sorted(capacity_factors):
@@ -464,7 +465,8 @@ def main() -> None:
     keys = jnp.asarray(rng.randint(0, G, N).astype(np.int32))
 
     def bench(layout: str, C: int) -> float:
-        vals = jnp.asarray(rng.rand(N, C).astype(np.float32))
+        vals = [jnp.asarray(rng.rand(N).astype(np.float32))
+                for _ in range(C)]
         fn = jax.jit(functools.partial(stacked_group_sums, n_groups=G,
                                        layout=layout, mode=args.mode))
         return time_fn(lambda: fn(keys, vals))

@@ -48,13 +48,13 @@ def count_partitioned(keys: jax.Array, cardinality: int, *,
 
     Thin wrapper: a COUNT is a fused sweep over a single all-ones weights
     column, so this delegates to the shared range-partitioned recipe in
-    ``columnar.stacked_group_sums`` (COUNT always rides in column 0 of the
-    stacked matrix — padded slots carry zero weight, so no dead-bin
-    correction is needed)."""
+    ``columnar.stacked_group_sums`` (COUNT always rides in measure column
+    0 — padded slots carry zero weight, so no dead-bin correction is
+    needed)."""
     clipped = jnp.clip(keys, 0, cardinality - 1).astype(jnp.int32)
-    ones = jnp.ones(keys.shape + (1,), jnp.float32)
+    ones = jnp.ones(keys.shape, jnp.float32)
     sums, overflow = stacked_group_sums(
-        clipped, ones, cardinality, layout="partitioned", mode=mode,
+        clipped, [ones], cardinality, layout="partitioned", mode=mode,
         n_partitions=n_partitions, capacity_factor=capacity_factor)
     return sums[:, 0], overflow
 

@@ -15,8 +15,8 @@ per Aggregate node — see planner.choose_aggregate for the cost model:
                  over the table for N aggregates.
   "dense"        fused-kernel sweep with positionally-chunked full-width
                  tables (no sort; exact): every (sum, avg, count) aggregate
-                 over one key column is stacked into a single values matrix
-                 and swept in ONE pass through the hash_aggregate Pallas
+                 over one key column rides in its own measure column, and
+                 all are swept in ONE pass through the hash_aggregate Pallas
                  kernel (VMEM-resident tables — the paper's
                  partition-then-per-thread-table recipe). Valid for key
                  domains up to DENSE_GROUP_LIMIT.
@@ -45,18 +45,19 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, Mapping, Optional, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.analytics.hashing import pad_partitions, partition_of
 from repro.analytics.plan import is_holistic, parse_quantile
-from repro.kernels.hash_aggregate import hash_aggregate_multi
+from repro.kernels.hash_aggregate import hash_aggregate
+from repro.kernels.hash_aggregate.kernel import TILE
 from repro.kernels.join_probe import join_probe
 
 # Largest key domain aggregated with full-width per-chunk tables (the
-# kernel's one-hot is (n_bins, block/8): 4096 x 128 fp32 = 2 MB VMEM per
+# kernel's one-hot is (n_bins, 128): 4096 x 128 fp32 = 2 MB VMEM per
 # row). Beyond this the kernel path range-partitions so each partition
 # table stays narrow.
 DENSE_GROUP_LIMIT = 4096
@@ -291,20 +292,20 @@ def group_aggregate(table: Table, key: str, n_groups: int,
     ``_count`` and ``_overflow`` (records beyond partition capacity on the
     kernel path; always 0 on the XLA path and the dense kernel path).
 
-    Both executors sum the same stacked matrix (``stacked_columns``): "xla"
-    with segment scatters, "kernel" with the fused kernel. ``layout``
-    overrides the kernel path's dense/partitioned choice (the cost-based
-    planner sets it per Aggregate node); None keeps the DENSE_GROUP_LIMIT
-    domain-size rule."""
+    Both executors sum the same measure columns (``stacked_columns``):
+    "xla" with segment scatters, "kernel" with the fused kernel.
+    ``layout`` overrides the kernel path's dense/partitioned choice (the
+    cost-based planner sets it per Aggregate node); None keeps the
+    DENSE_GROUP_LIMIT domain-size rule."""
     if executor not in ("xla", "kernel"):
         raise ValueError(f"unknown executor {executor!r}")
-    keys, vals, src = stacked_columns(table, key, n_groups, aggs)
+    keys, cols, src = stacked_columns(table, key, n_groups, aggs)
     if executor == "xla":
         layout = "xla"
     elif layout is None:
         layout = "dense" if n_groups <= DENSE_GROUP_LIMIT else "partitioned"
     sums, overflow = stacked_group_sums(
-        keys, vals, n_groups, layout=layout, mode=mode,
+        keys, cols, n_groups, layout=layout, mode=mode,
         n_partitions=n_partitions, capacity_factor=capacity_factor)
     out = finalize_stacked(
         aggs, src, sums,
@@ -315,11 +316,14 @@ def group_aggregate(table: Table, key: str, n_groups: int,
 
 def stacked_columns(table: Table, key: str, n_groups: int,
                     aggs: Mapping[str, Tuple[str, str]]
-                    ) -> Tuple[jax.Array, jax.Array, list]:
-    """(keys, stacked values matrix, distinct sum/avg source columns).
+                    ) -> Tuple[jax.Array, List[jax.Array], list]:
+    """(keys, measure columns, distinct sum/avg source columns).
 
-    Column 0 of the matrix carries the selection weights (COUNT); masked
-    rows have weight 0 so they vanish from every fused sum."""
+    Column 0 carries the selection weights (COUNT), column 1 + i the
+    weighted ``src[i]``; masked rows have weight 0 so they vanish from
+    every fused sum. The columns stay separate (N,) arrays: every layout
+    reads them one at a time, and an (N, C) stack would cost a relayout
+    pass of the whole table on the TPU."""
     keys = jnp.clip(table.col(key), 0, n_groups - 1).astype(jnp.int32)
     w = table.weights()
     src: list = []                       # distinct sum/avg source columns
@@ -329,16 +333,16 @@ def stacked_columns(table: Table, key: str, n_groups: int,
         elif (op not in ("sum", "avg", "count", "max", "min")
               and not is_holistic(op)):
             raise ValueError(f"unknown agg op {op!r}")
-    vals = jnp.stack(
-        [w] + [table.col(c).astype(jnp.float32) * w for c in src], axis=1)
-    return keys, vals, src
+    cols = [w] + [table.col(c).astype(jnp.float32) * w for c in src]
+    return keys, cols, src
 
 
-def stacked_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int, *,
-                       layout: str, mode: Optional[str] = None,
-                       n_partitions: int = 64, capacity_factor: float = 2.0
+def stacked_group_sums(keys: jax.Array, cols: Sequence[jax.Array],
+                       n_groups: int, *, layout: str,
+                       mode: Optional[str] = None, n_partitions: int = 64,
+                       capacity_factor: float = 2.0
                        ) -> Tuple[jax.Array, jax.Array]:
-    """Per-group sums of a stacked (N, C) values matrix under one layout.
+    """Per-group sums of C (N,) measure columns under one layout.
 
     The single physical primitive every grouped-sum lowering shares: the
     local executor, the distributed per-shard partials (planner.py) and
@@ -349,30 +353,30 @@ def stacked_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int, *,
     rounded once to f32: each layout adds it in int32 wherever a partial
     could pass 2^24, the last integer f32 accumulation keeps."""
     if layout == "xla":
-        return _segment_sums_xla(keys, vals, n_groups), \
+        return _segment_sums_xla(keys, cols, n_groups), \
             jnp.zeros((), jnp.int32)
     if layout == "dense":
-        return _fused_dense(keys, vals, n_groups, mode=mode), \
+        return _fused_dense(keys, cols, n_groups, mode=mode), \
             jnp.zeros((), jnp.int32)
     if layout == "partitioned":
         sums, overflow = _fused_partitioned(
-            keys, vals, n_groups, mode=mode, n_partitions=n_partitions,
+            keys, cols, n_groups, mode=mode, n_partitions=n_partitions,
             capacity_factor=capacity_factor)
         return sums, overflow.astype(jnp.int32)
     raise ValueError(f"unknown layout {layout!r}")
 
 
-def _segment_sums_xla(keys: jax.Array, vals: jax.Array,
+def _segment_sums_xla(keys: jax.Array, cols: Sequence[jax.Array],
                       n_groups: int) -> jax.Array:
-    """(n_groups, C) sums of the stacked (N, C) matrix by segment scatters.
+    """(n_groups, C) sums of the measure columns by segment scatters.
 
     A scatter-add accumulates in f32 in row order, so one big group drifts
     (a count stops at 2^24). Rows are cut into about sqrt(N) position
     chunks, as many as keep the chunk tables within SUM_CHUNK_TABLE
     entries; each chunk sums into its own table and the tables are added,
-    the count column in int32. One scatter per column: a scatter of the
+    the count column in int32. One scatter per column: a scatter of an
     (N, C) matrix makes the TPU compiler put C on the 128-lane axis."""
-    N, C = vals.shape
+    N = keys.shape[0]
     k = max(1, min(math.isqrt(N), SUM_CHUNK_TABLE // n_groups))
     seg = (jnp.arange(N, dtype=jnp.int32) // -(-N // k)) * n_groups + keys
 
@@ -380,9 +384,8 @@ def _segment_sums_xla(keys: jax.Array, vals: jax.Array,
         return jax.ops.segment_sum(v, seg, num_segments=k * n_groups
                                    ).reshape(k, n_groups).sum(axis=0)
 
-    counts = chunk_sums(vals[:, 0].astype(jnp.int32)).astype(jnp.float32)
-    return jnp.stack([counts] + [chunk_sums(vals[:, c]) for c in range(1, C)],
-                     axis=1)
+    counts = chunk_sums(cols[0].astype(jnp.int32)).astype(jnp.float32)
+    return jnp.stack([counts] + [chunk_sums(c) for c in cols[1:]], axis=1)
 
 
 def _segment_selection(keys: jax.Array, vals: jax.Array, n_groups: int):
@@ -521,8 +524,16 @@ def finalize_stacked(aggs: Mapping[str, Tuple[str, str]], src: list,
     return out
 
 
-def _fused_dense(keys: jax.Array, vals: jax.Array, n_groups: int, *,
-                 mode: Optional[str], block: int = 1024) -> jax.Array:
+def _tile_fold(col: jax.Array, pad: int) -> jax.Array:
+    """The kernel's (R, 8, 128) operand: ``col`` padded with ``pad`` zeros
+    to a whole number of 1024-record tiles. A 1-D column's TPU layout is
+    1024-element tiles, each one (8, 128) tile, so the reshape is a
+    bitcast: the pad is the only pass the fold costs."""
+    return jnp.pad(col, (0, pad)).reshape(-1, 8, 128)
+
+
+def _fused_dense(keys: jax.Array, cols: Sequence[jax.Array], n_groups: int,
+                 *, mode: Optional[str]) -> jax.Array:
     """Small key domain: positional chunking, full-width tables, no sort.
 
     Rows are split into chunks by position; each chunk's (C, n_bins) table
@@ -530,18 +541,15 @@ def _fused_dense(keys: jax.Array, vals: jax.Array, n_groups: int, *,
     no partitioning pass, no overflow possible. Padding rows carry zero
     values, so their bin placement is irrelevant. A chunk holds fewer than
     F32_EXACT_COUNT rows, so its counts are exact and add up in int32."""
-    N, C = vals.shape
+    N = keys.shape[0]
     bins = max(128, -(-n_groups // 128) * 128)
-    n_chunks = max(8, -(-N // (F32_EXACT_COUNT - block))) \
-        if N >= 8 * block else 1
-    per_chunk = -(-N // n_chunks)
-    t = -(-per_chunk // block) * block
-    pad = n_chunks * t - N
-    k = jnp.pad(keys, (0, pad))
-    v = jnp.pad(vals.T, ((0, 0), (0, pad)))          # measure-major (C, N)
-    table = hash_aggregate_multi(k.reshape(n_chunks, t),
-                                 v.reshape(C, n_chunks, t).swapaxes(0, 1),
-                                 n_bins=bins, block=block, mode=mode)
+    n_chunks = max(8, -(-N // (F32_EXACT_COUNT - TILE))) \
+        if N >= 8 * TILE else 1
+    chunk_tiles = -(-N // (n_chunks * TILE))
+    pad = n_chunks * chunk_tiles * TILE - N
+    table = hash_aggregate(_tile_fold(keys, pad),
+                           [_tile_fold(c, pad) for c in cols],
+                           n_parts=n_chunks, n_bins=bins, mode=mode)
     counts = table[:, 0].astype(jnp.int32).sum(axis=0).astype(jnp.float32)
     return table.sum(axis=0).at[0].set(counts).T[:n_groups]
 
@@ -561,9 +569,9 @@ def partition_layout(n_rows: int, n_groups: int, n_partitions: int,
     return P, range_size, bins, _capacity(n_rows, P, capacity_factor, block)
 
 
-def _fused_partitioned(keys: jax.Array, vals: jax.Array, n_groups: int, *,
-                       mode: Optional[str], n_partitions: int,
-                       capacity_factor: float, block: int = 1024
+def _fused_partitioned(keys: jax.Array, cols: Sequence[jax.Array],
+                       n_groups: int, *, mode: Optional[str],
+                       n_partitions: int, capacity_factor: float
                        ) -> Tuple[jax.Array, jax.Array]:
     """Large key domain: range partition, then fused per-partition tables.
 
@@ -571,20 +579,23 @@ def _fused_partitioned(keys: jax.Array, vals: jax.Array, n_groups: int, *,
     partition-local slot (key % range_size) collision-free, so the kernel
     result is EXACT whenever no partition overflows its capacity; overflow
     is counted and returned, as in aggregate.count_partitioned. The
-    layout's sizes come from ``partition_layout``."""
-    N, C = vals.shape
+    layout's sizes come from ``partition_layout``; a partition's slots are
+    whole 1024-record tiles, so each row of the (P, slots) layout is a run
+    of the kernel's (R, 8, 128) fold."""
+    C = len(cols)
     n_partitions, range_size, bins, pad_t = partition_layout(
-        N, n_groups, n_partitions, capacity_factor, block)
+        keys.shape[0], n_groups, n_partitions, capacity_factor, TILE)
     part = jnp.clip(keys // range_size, 0, n_partitions - 1)
     order = jnp.argsort(part, stable=True)
     counts_p = jnp.bincount(part, length=n_partitions)
     starts = jnp.cumsum(counts_p) - counts_p
-    # measure columns travel one by one and are stacked measure-major
+    # measure columns travel one by one through the same gather as the keys
     pk, pv, overflow = pad_partitions(
-        keys[order], [vals[:, c][order] for c in range(C)], starts,
-        counts_p, n_partitions, pad_t)
+        keys[order], [c[order] for c in cols], starts, counts_p,
+        n_partitions, pad_t)
     local = jnp.where(pk < 0, 0, pk % range_size)   # padded vals are zero
-    table = hash_aggregate_multi(local, jnp.stack(pv, axis=1), n_bins=bins,
-                                 block=block, mode=mode)
+    table = hash_aggregate(local.reshape(-1, 8, 128),
+                           [v.reshape(-1, 8, 128) for v in pv],
+                           n_parts=n_partitions, n_bins=bins, mode=mode)
     flat = table[:, :, :range_size].transpose(0, 2, 1)
     return flat.reshape(n_partitions * range_size, C)[:n_groups], overflow

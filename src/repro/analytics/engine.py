@@ -336,18 +336,20 @@ def morsel_slice_columns(cols, lo, length: int):
             for c, a in cols.items()}
 
 
-def morsel_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int, *,
-                      layout: str = "xla", mode: Optional[str] = None,
-                      n_partitions: int = 64, capacity_factor: float = 2.0
+def morsel_group_sums(keys: jax.Array, cols: Sequence[jax.Array],
+                      n_groups: int, *, layout: str = "xla",
+                      mode: Optional[str] = None, n_partitions: int = 64,
+                      capacity_factor: float = 2.0
                       ) -> Tuple[jax.Array, jax.Array]:
-    """Partial (n_groups, C) sums over ONE morsel's (already-sliced) rows.
+    """Partial (n_groups, C) sums of C measure columns over ONE morsel's
+    (already-sliced) rows.
 
     A named delegation to the shared stacked-group-sums recipe: the morsel
     path exercises the SAME physical layouts the planner chooses between,
     and the (sums, int32 overflow) pair is exactly what
     merge_morsel_partials folds."""
     return stacked_group_sums(
-        keys, vals, n_groups, layout=layout, mode=mode,
+        keys, cols, n_groups, layout=layout, mode=mode,
         n_partitions=n_partitions, capacity_factor=capacity_factor)
 
 
@@ -423,8 +425,10 @@ def interleave_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int,
                           ) -> Tuple[jax.Array, jax.Array]:
     """INTERLEAVE backend: route records to bucket-interleaved owners
     (all-to-all of the DATA, O(N) wire bytes), aggregate once on the owner,
-    then republish. ``aggregate_fn(slot_ids, vals, n_slots) -> (sums, ovf)``
-    is the shard-local aggregation (the planner passes the cost-chosen
+    then republish. ``vals`` is the (N, C) measure matrix, weights in
+    column 0, routed as one payload; ``aggregate_fn(slot_ids, cols,
+    n_slots) -> (sums, ovf)`` is the shard-local aggregation of the
+    received matrix's C columns (the planner passes the cost-chosen
     lowering, so the fused kernel path composes with this placement plan).
     NOTE: the routed (n, cap) buffer parks every padding slot on one extra
     drop slot with zero values, so ``aggregate_fn`` must use a layout whose
@@ -436,11 +440,7 @@ def interleave_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int,
     Exchange node's capacity so the executed routing can never drift from
     the rendered plan. Returns ((n_groups, C) replicated, overflow)."""
     G_pad = n_groups + (-n_groups % n)
-    if vals.ndim > 1:
-        # column 0 of a stacked matrix carries the selection weights
-        owner = route_owner(keys, vals[:, 0] > 0, n)
-    else:
-        owner = keys % n
+    owner = route_owner(keys, vals[:, 0] > 0, n)
     cap = (capacity if capacity is not None
            else routing_capacity(keys.shape[0], n, capacity_factor))
     k_out, v_out, route_ovf = route_records(keys, vals, n, owner, cap)
@@ -451,9 +451,10 @@ def interleave_group_sums(keys: jax.Array, vals: jax.Array, n_groups: int,
     # owned group g lives in local slot g // n (keys % n == my shard index)
     n_slots = G_pad // n
     slot = jnp.where(k_in >= 0, k_in // n, n_slots)      # OOB drop slot
-    local, agg_ovf = aggregate_fn(slot.reshape(-1),
-                                  v_in.reshape((-1,) + v_in.shape[2:]),
-                                  n_slots + 1)
+    v_in = v_in.reshape(-1, v_in.shape[-1])
+    local, agg_ovf = aggregate_fn(
+        slot.reshape(-1), [v_in[:, c] for c in range(v_in.shape[1])],
+        n_slots + 1)
     gathered = jax.lax.all_gather(local[:n_slots], axis, tiled=True)
     g = jnp.arange(n_groups)
     full = gathered[(g % n) * n_slots + g // n]

@@ -328,7 +328,7 @@ def aggregate_costs(n_rows: int, n_groups: int, n_cols: int,
                     profile: Optional[CostProfile] = None
                     ) -> Dict[str, float]:
     """Pass-equivalent cost of each physical Aggregate layout (see module
-    docstring for the formulas). ``n_cols`` counts the stacked matrix width:
+    docstring for the formulas). ``n_cols`` counts the measure columns:
     1 (COUNT/weights) + distinct sum/avg source columns. The constants come
     from ``profile`` — callers that cache on a profile snapshot must pass
     it explicitly so a concurrent recalibration cannot leak into a plan
@@ -485,7 +485,7 @@ def choose_dist_topk(n_groups: int, k: int, n_shards: int,
 
 
 def stacked_width(aggs: Tuple[Tuple[str, Tuple[str, str]], ...]) -> int:
-    """Width of the stacked values matrix: weights + distinct sum/avg."""
+    """Number of measure columns: weights + distinct sum/avg."""
     return 1 + len({c for _, (op, c) in aggs if op in ("sum", "avg")})
 
 
@@ -1421,7 +1421,7 @@ class _DistributedExecutor(_LocalExecutor):
     replicated.
 
     Two Exchange kinds execute FUSED inside their consuming aggregate
-    rather than standalone: "gather" (the stacked (keys, vals) matrix is
+    rather than standalone: "gather" (the keys and measure columns are
     gathered, not the whole table — fewer columns on the wire, and the
     holistic path must see the un-gathered records exactly once) and the
     partial-sums hash exchange of a pushed-down aggregate (the routing and
@@ -1554,10 +1554,10 @@ class _DistributedExecutor(_LocalExecutor):
         """Local (n_groups, C) stacked partial sums — the below-the-
         exchange half of push-down and of the FT/LA partial-table merges."""
         t = self.run(node.child)
-        keys, vals, _src = stacked_columns(t, node.key, node.n_groups,
+        keys, cols, _src = stacked_columns(t, node.key, node.n_groups,
                                            dict(node.aggs))
         return stacked_group_sums(
-            keys, vals, node.n_groups, layout=node.layout,
+            keys, cols, node.n_groups, layout=node.layout,
             mode=self.ctx.mode, n_partitions=self.ctx.n_partitions,
             capacity_factor=self.agg_cf)
 
@@ -1622,27 +1622,27 @@ class _DistributedExecutor(_LocalExecutor):
         if merge == "placed":
             # route-once: every group's rows are co-located, so the
             # per-shard tables are DISJOINT and the psum is exact
-            keys, vals, _ = stacked_columns(t, node.key, G, dict(dist_aggs))
-            sums, ovf = self._stacked(keys, vals, G, node.layout)
+            keys, cols, _ = stacked_columns(t, node.key, G, dict(dist_aggs))
+            sums, ovf = self._stacked(keys, cols, G, node.layout)
             return jax.lax.psum(sums, axis), jax.lax.psum(ovf, axis)
         if merge == "owner":
-            keys, vals, _ = stacked_columns(t, node.key, G, dict(dist_aggs))
+            keys, cols, _ = stacked_columns(t, node.key, G, dict(dist_aggs))
             agg_fn = functools.partial(self._stacked, layout=node.layout)
             # the Exchange node's capacity drives the routing: execution
             # can never drift from the rendered physical plan
             return interleave_group_sums(
-                keys, vals, G, axis, n, agg_fn,
+                keys, jnp.stack(cols, axis=1), G, axis, n, agg_fn,
                 capacity_factor=self.ctx.capacity_factor,
                 capacity=node.child.capacity)
         if merge == "gather":
-            keys, vals, _ = stacked_columns(t, node.key, G, dict(dist_aggs))
-            ak, av = gather_rows((keys, vals), axis)
-            return self._stacked(ak, av, G, node.layout)
+            keys, cols, _ = stacked_columns(t, node.key, G, dict(dist_aggs))
+            ak, acols = gather_rows((keys, cols), axis)
+            return self._stacked(ak, acols, G, node.layout)
         raise ValueError(f"unknown aggregate merge {merge!r}")
 
-    def _stacked(self, keys, vals, n_groups, layout):
+    def _stacked(self, keys, cols, n_groups, layout):
         return stacked_group_sums(
-            keys, vals, n_groups, layout=layout, mode=self.ctx.mode,
+            keys, cols, n_groups, layout=layout, mode=self.ctx.mode,
             n_partitions=self.ctx.n_partitions, capacity_factor=self.agg_cf)
 
     def _order_stat_fn(self, t: Table, node: PH.PAggregate, G: int):

@@ -676,10 +676,10 @@ def _morsel_decompose(plan: L.LogicalPlan, tables, ctx: ExecutionContext):
             key = "_g0"
         else:
             key = root.key
-        keys, vals, src = stacked_columns(t, key, n_groups, aggs)
-        layout = planner.choose_aggregate(length, n_groups, vals.shape[1],
+        keys, cols, src = stacked_columns(t, key, n_groups, aggs)
+        layout = planner.choose_aggregate(length, n_groups, len(cols),
                                           ctx.executor, profile)
-        return morsel_group_sums(keys, vals, n_groups, layout=layout,
+        return morsel_group_sums(keys, cols, n_groups, layout=layout,
                                  mode=ctx.mode,
                                  n_partitions=ctx.n_partitions,
                                  capacity_factor=ctx.capacity_factor)

@@ -14,7 +14,7 @@ Kernels:
   join_probe       partition-wise broadcast-compare probe (W3/W4 hot loop)
 """
 from repro.kernels.flash_attention import decode_attention, flash_attention
-from repro.kernels.hash_aggregate import hash_aggregate, hash_aggregate_multi
+from repro.kernels.hash_aggregate import hash_aggregate
 from repro.kernels.join_probe import join_probe
 from repro.kernels.radix_partition import block_histograms, radix_partition
 from repro.kernels.rglru_scan import linear_scan

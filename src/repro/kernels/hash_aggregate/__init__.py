@@ -1,2 +1,1 @@
-from repro.kernels.hash_aggregate.ops import (hash_aggregate,
-                                              hash_aggregate_multi)
+from repro.kernels.hash_aggregate.ops import hash_aggregate

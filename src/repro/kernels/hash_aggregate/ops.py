@@ -1,36 +1,28 @@
-"""Public partitioned-aggregation ops with mode dispatch."""
+"""Public partitioned-aggregation op with mode dispatch."""
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 
 from repro.kernels.common import kernel_mode
-from repro.kernels.hash_aggregate.kernel import hash_aggregate_multi_pallas
-from repro.kernels.hash_aggregate.ref import hash_aggregate_multi_ref
+from repro.kernels.hash_aggregate.kernel import hash_aggregate_pallas
+from repro.kernels.hash_aggregate.ref import hash_aggregate_ref
 
 
-def hash_aggregate_multi(ids: jax.Array, vals: jax.Array, *, n_bins: int,
-                         block: int = 1024,
-                         mode: Optional[str] = None) -> jax.Array:
-    """Fused partition-local segment sums over C stacked measure columns.
+def hash_aggregate(ids: jax.Array, cols: Sequence[jax.Array], *,
+                   n_parts: int, n_bins: int, tiles: int = 1,
+                   mode: Optional[str] = None) -> jax.Array:
+    """Fused part-local segment sums of C measure columns.
 
-    ids: (P, T); vals: (P, C, T) -> (P, C, n_bins). The one-hot/ids stream
-    cost is paid once for all C aggregates (see kernel.py)."""
+    ids and each of cols: (R, 8, 128), the fold of a 1-D column padded to
+    a multiple of 1024 * tiles * n_parts records; part p is a contiguous
+    range of R / n_parts tiles. Returns (n_parts, C, n_bins). The
+    one-hot/ids stream cost is paid once for all C aggregates (see
+    kernel.py)."""
     resolved = kernel_mode(mode)
-    if resolved == "pallas":
-        return hash_aggregate_multi_pallas(ids, vals, n_bins=n_bins,
-                                           block=block)
-    if resolved == "interpret":
-        return hash_aggregate_multi_pallas(ids, vals, n_bins=n_bins,
-                                           block=block, interpret=True)
-    return hash_aggregate_multi_ref(ids, vals, n_bins=n_bins)
-
-
-def hash_aggregate(ids: jax.Array, vals: jax.Array, *, n_bins: int,
-                   block: int = 1024, mode: Optional[str] = None) -> jax.Array:
-    """Partition-local segment sums. ids, vals: (P, T) -> (P, n_bins).
-
-    Thin single-aggregate wrapper over :func:`hash_aggregate_multi`."""
-    return hash_aggregate_multi(ids, vals[:, None], n_bins=n_bins,
-                                block=block, mode=mode)[:, 0]
+    if resolved == "ref":
+        return hash_aggregate_ref(ids, cols, n_parts=n_parts, n_bins=n_bins)
+    return hash_aggregate_pallas(ids, cols, n_parts=n_parts, n_bins=n_bins,
+                                 tiles=tiles,
+                                 interpret=resolved == "interpret")

@@ -5,9 +5,8 @@ import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.hash_aggregate import hash_aggregate, hash_aggregate_multi
-from repro.kernels.hash_aggregate.ref import (hash_aggregate_multi_ref,
-                                              hash_aggregate_ref)
+from repro.kernels.hash_aggregate import hash_aggregate
+from repro.kernels.hash_aggregate.ref import hash_aggregate_ref
 from repro.kernels.join_probe import join_probe
 from repro.kernels.join_probe.ref import join_probe_ref
 from repro.kernels.radix_partition import (block_histograms,
@@ -97,43 +96,68 @@ def test_radix_partition_orders_digits(rng):
                                   np.cumsum(counts) - counts)
 
 
-@pytest.mark.parametrize("P,T,bins,block", [(2, 512, 128, 256),
-                                            (4, 1024, 512, 512),
-                                            (1, 256, 256, 128)])
+def _fold(x):
+    """A 1-D column (length a multiple of 1024) in the kernel's
+    (R, 8, 128) operand form."""
+    return jnp.asarray(x).reshape(-1, 8, 128)
+
+
+def _part_sums(ids, cols, n_parts, n_bins):
+    """numpy oracle: (n_parts, C, n_bins) sums over contiguous parts."""
+    out = np.zeros((n_parts, len(cols), n_bins), np.float64)
+    ids = ids.reshape(n_parts, -1)
+    for c, v in enumerate(cols):
+        for p in range(n_parts):
+            np.add.at(out[p, c], ids[p], v.reshape(n_parts, -1)[p])
+    return out
+
+
+@pytest.mark.parametrize("P,T,bins,block", [(2, 2048, 128, 1024),
+                                            (4, 4096, 512, 2048),
+                                            (1, 3072, 256, 1024)])
 def test_hash_aggregate_interpret(rng, P, T, bins, block):
-    ids = jnp.asarray(rng.randint(0, bins, (P, T)), jnp.int32)
-    vals = jnp.asarray(rng.rand(P, T), jnp.float32)
-    ref = hash_aggregate_ref(ids, vals, n_bins=bins)
-    got = hash_aggregate(ids, vals, n_bins=bins, block=block,
-                         mode="interpret")
+    """One measure column, P contiguous parts of T records, ``block``
+    records per grid step."""
+    ids = rng.randint(0, bins, P * T).astype(np.int32)
+    vals = rng.rand(P * T).astype(np.float32)
+    ref = hash_aggregate_ref(_fold(ids), [_fold(vals)], n_parts=P,
+                             n_bins=bins)
+    got = hash_aggregate(_fold(ids), [_fold(vals)], n_parts=P, n_bins=bins,
+                         tiles=block // 1024, mode="interpret")
+    assert got.shape == (P, 1, bins)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got),
+                               _part_sums(ids, [vals], P, bins), atol=1e-4)
 
 
-@pytest.mark.parametrize("P,T,bins,C,block", [(2, 512, 128, 3, 256),
-                                              (4, 1024, 256, 7, 512),
-                                              (1, 256, 128, 1, 128)])
+@pytest.mark.parametrize("P,T,bins,C,block", [(2, 2048, 128, 3, 1024),
+                                              (4, 4096, 256, 7, 2048),
+                                              (1, 1024, 128, 1, 1024)])
 def test_hash_aggregate_multi_interpret(rng, P, T, bins, C, block):
-    """Fused multi-aggregate kernel vs oracle, incl. the C=1 edge."""
-    ids = jnp.asarray(rng.randint(0, bins, (P, T)), jnp.int32)
-    vals = jnp.asarray(rng.randn(P, C, T), jnp.float32)
-    ref = hash_aggregate_multi_ref(ids, vals, n_bins=bins)
-    got = hash_aggregate_multi(ids, vals, n_bins=bins, block=block,
-                               mode="interpret")
+    """Fused multi-aggregate kernel vs oracles, incl. the C=1 edge."""
+    ids = rng.randint(0, bins, P * T).astype(np.int32)
+    cols = [rng.randn(P * T).astype(np.float32) for _ in range(C)]
+    ref = hash_aggregate_ref(_fold(ids), [_fold(v) for v in cols],
+                             n_parts=P, n_bins=bins)
+    got = hash_aggregate(_fold(ids), [_fold(v) for v in cols], n_parts=P,
+                         n_bins=bins, tiles=block // 1024, mode="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got),
+                               _part_sums(ids, cols, P, bins), atol=1e-4)
 
 
 def test_hash_aggregate_multi_matches_stacked_singles(rng):
     """The fused sweep equals C independent single-aggregate sweeps."""
-    P, T, bins, C, block = 2, 768, 128, 4, 256
-    ids = jnp.asarray(rng.randint(0, bins, (P, T)), jnp.int32)
-    vals = jnp.asarray(rng.randn(P, C, T), jnp.float32)
-    fused = hash_aggregate_multi(ids, vals, n_bins=bins, block=block,
-                                 mode="interpret")
+    P, T, bins, C = 2, 3072, 128, 4
+    ids = _fold(rng.randint(0, bins, P * T).astype(np.int32))
+    cols = [_fold(rng.randn(P * T).astype(np.float32)) for _ in range(C)]
+    fused = hash_aggregate(ids, cols, n_parts=P, n_bins=bins,
+                           mode="interpret")
     for c in range(C):
-        single = hash_aggregate(ids, vals[:, c], n_bins=bins, block=block,
+        single = hash_aggregate(ids, [cols[c]], n_parts=P, n_bins=bins,
                                 mode="interpret")
         np.testing.assert_allclose(np.asarray(fused[:, c]),
-                                   np.asarray(single), atol=1e-4)
+                                   np.asarray(single[:, 0]), atol=1e-4)
 
 
 def test_join_probe_interpret(rng):

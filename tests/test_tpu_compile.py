@@ -9,6 +9,7 @@ may load the TPU library, so nothing here touches it while tests are
 collected.
 """
 import functools
+import math
 import os
 import re
 
@@ -19,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analytics import planner, tpch
 from repro.analytics.columnar import join_layout, partition_layout
-from repro.kernels.hash_aggregate.kernel import hash_aggregate_multi_pallas
+from repro.kernels.hash_aggregate.kernel import TILE, hash_aggregate_pallas
 from repro.kernels.join_probe.kernel import join_probe_pallas
 from repro.kernels.radix_partition.kernel import block_histograms_pallas
 
@@ -47,14 +48,40 @@ def _shape(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _sf10_tables(sharding):
+    schema = tpch.generate(scale=0.001).tables
+    return {t: {c: _shape(sharding, (SF10_ROWS[t],), a.dtype)
+                for c, a in cols.items()} for t, cols in schema.items()}
+
+
+def _entry_ops(hlo):
+    """(name, opcode, largest result element count) of each instruction
+    of the ENTRY computation."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    ops = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(",
+                         entry, re.M):
+        dims = [math.prod(int(d) for d in shape.split(",") if d)
+                for shape in re.findall(r"\[([\d,]*)\]", m.group(2))]
+        ops.append((m.group(1), m.group(3), max(dims, default=1)))
+    return ops
+
+
+def _folded(sharding, rows, dtype):
+    """A 1-D column of ``rows`` records in the kernel's (R, 8, 128) fold."""
+    return _shape(sharding, (rows // TILE, 8, 128), dtype)
+
+
 def test_q1_dense_aggregate_compiles(one_chip):
-    """Q1's fused sweep: 8 positional chunks of SF 10 lineitem, 7 stacked
-    measures, 6 groups in a 128-bin table."""
-    P, T, C = 8, 7_500_800, 7
-    fn = functools.partial(hash_aggregate_multi_pallas, n_bins=128,
-                           block=1024)
-    hlo = _compiled_text(fn, _shape(one_chip, (P, T)),
-                         _shape(one_chip, (P, C, T), jnp.float32))
+    """Q1's fused sweep: 8 positional chunks of SF 10 lineitem (60006400
+    records after padding), the weights and 4 weighted measures as
+    separate folded columns, 6 groups in a 128-bin table."""
+    P, rows, C = 8, 60_006_400, 5
+    fn = functools.partial(hash_aggregate_pallas, n_parts=P, n_bins=128)
+    hlo = _compiled_text(
+        lambda ids, *cols: fn(ids, cols), _folded(one_chip, rows, jnp.int32),
+        *[_folded(one_chip, rows, jnp.float32)] * C)
     assert "tpu_custom_call" in hlo
 
 
@@ -62,11 +89,12 @@ def test_q18_partitioned_aggregate_compiles(one_chip):
     """Q18's per-order sums: 15M groups over 60M rows, range-partitioned
     so each partition table stays within MAX_PARTITION_BINS."""
     P, _, bins, pad_t = partition_layout(SF10_ROWS["lineitem"],
-                                         SF10_ROWS["orders"], 64, 2.0, 1024)
-    fn = functools.partial(hash_aggregate_multi_pallas, n_bins=bins,
-                           block=1024)
-    hlo = _compiled_text(fn, _shape(one_chip, (P, pad_t)),
-                         _shape(one_chip, (P, 2, pad_t), jnp.float32))
+                                         SF10_ROWS["orders"], 64, 2.0, TILE)
+    fn = functools.partial(hash_aggregate_pallas, n_parts=P, n_bins=bins)
+    hlo = _compiled_text(
+        lambda ids, *cols: fn(ids, cols),
+        _folded(one_chip, P * pad_t, jnp.int32),
+        *[_folded(one_chip, P * pad_t, jnp.float32)] * 2)
     assert "tpu_custom_call" in hlo
 
 
@@ -91,9 +119,7 @@ def test_radix_histogram_compiles(one_chip):
 def test_q1_kernel_plan_compiles(one_chip):
     """The whole q1 plan under the kernel executor, forced to Pallas: the
     planner lowers it, and the TPU compiler accepts the program."""
-    schema = tpch.generate(scale=0.001).tables
-    tables = {t: {c: _shape(one_chip, (SF10_ROWS[t],), a.dtype)
-                  for c, a in cols.items()} for t, cols in schema.items()}
+    tables = _sf10_tables(one_chip)
     ctx = planner.ExecutionContext(executor="kernel", mode="pallas")
     plan = planner.compile_plan(tpch.LOGICAL_QUERIES["q1"], tables, ctx)
     hlo = plan.fn.lower(tables, {}).compile().as_text()
@@ -101,3 +127,21 @@ def test_q1_kernel_plan_compiles(one_chip):
     # named in a profile: the plan's module and the kernel's instruction
     assert hlo.startswith("HloModule jit_plan_q1")
     assert re.search(r"%hash_aggregate[.\d]* = .*tpu_custom_call", hlo)
+
+
+def test_q1_cost_plan_stages_no_relayout(one_chip):
+    """q1 as the SF 10 scan cell runs it (cost executor, Pallas kernel):
+    every operand of the dense aggregate is a bitcast of its padded
+    column, so the ENTRY computation holds no relayout loop, no
+    concatenation and no copy or transpose of a whole column."""
+    tables = _sf10_tables(one_chip)
+    ctx = planner.ExecutionContext(executor="cost", mode="pallas")
+    plan = planner.compile_plan(tpch.LOGICAL_QUERIES["q1"], tables, ctx)
+    hlo = plan.fn.lower(tables, {}).compile().as_text()
+    ops = _entry_ops(hlo)
+    assert any(name.startswith("hash_aggregate") for name, _, _ in ops)
+    assert not [o for o in ops if o[1] in ("while", "concatenate")]
+    big = SF10_ROWS["lineitem"]
+    assert not [o for o in ops if o[2] >= big and (
+        o[1] in ("copy", "transpose")
+        or o[0].startswith(("copy_bitcast_fusion", "transpose")))]
