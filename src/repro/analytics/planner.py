@@ -64,10 +64,10 @@ equivalent passes over the input rows:
 
 Compiled plans live in a bounded LRU cache keyed by (logical plan
 structure, context key, table shape signature, cost profile); the cache
-VALUE is the (physical plan, jitted executable) pair, so the physical
-tree is inspectable for every cached entry. Join build-side argsort
-indexes are pooled across calls keyed on column-array *identity* and
-enter the compiled plan as traced arguments.
+VALUE is the (physical plan, jitted executable, wire volume) triple, so
+the physical tree is inspectable for every cached entry. Join build-side
+argsort indexes are pooled across calls keyed on column-array *identity*
+and enter the compiled plan as traced arguments.
 """
 from __future__ import annotations
 
@@ -1772,6 +1772,142 @@ def _true_rows(tables) -> Dict[str, int]:
             for t, cols in tables.items()}
 
 
+# ---------------------------------------------------------------------------
+# wire volume: what a mesh plan's Exchanges move between chips per call
+# ---------------------------------------------------------------------------
+class ExchangeWire(NamedTuple):
+    """Bytes one chip receives from the other chips per call through a
+    plan's Exchanges, and the number of distinct Exchange nodes."""
+    bytes: int
+    exchanges: int
+
+
+_F32 = jnp.dtype(jnp.float32)
+_I32 = jnp.dtype(jnp.int32)
+
+
+def exchange_wire(phys: PH.PhysicalPlan, tables) -> ExchangeWire:
+    """The wire volume of ``phys`` from its static buffer shapes and the
+    column dtypes of ``tables`` (arrays or ShapeDtypeStructs), counted as
+    the distributed executor moves the data. With n shards, per call and
+    per chip:
+
+    - broadcast: (n-1) x the child's rows per shard x (its columns + the
+      float32 mask);
+    - hash on a key, run alone (partitioned-join sides): (n-1) x
+      ``capacity`` x (the child's columns + the float32 weights);
+    - hash fused into an owner or pushdown merge: (n-1) x ``capacity`` x
+      (int32 key + C float32 stacked columns, weights first), plus the
+      all-gather that republishes the merged (ceil(G/n), C) table;
+    - gather fused into a PREFERRED PAggregate: (n-1) x rows x (int32 key
+      + C float32 columns); the candidates TopK's gather: (n-1) x k x
+      (float32 value + int32 slot);
+    - allreduce and reduce_scatter merges of the (G, C) partial table,
+      and the route-once (``placed``) merge's psum of it, which replaced
+      an Exchange: 2 (n-1)/n of the table, as a ring all-reduce receives
+      it.
+
+    ``capacity`` counts every slot, padding included: this is what the
+    links carry, where ``explain``'s ``moved~`` estimates the live rows
+    only. Scalar psums and the order-statistic (median, quantile) routes
+    are not counted. Host-side arithmetic: nothing of it enters the
+    jitted program."""
+    n = phys.n_shards
+    count = len(PH.exchanges(phys.root))
+    if n == 1:
+        return ExchangeWire(0, count)
+    dtypes = {t: {c: jnp.dtype(a.dtype) for c, a in cols.items()}
+              for t, cols in tables.items()}
+    cols_memo: Dict[PH.PNode, Dict[str, jnp.dtype]] = {}
+    seen = set()
+    total = 0
+
+    def cols(node) -> Dict[str, jnp.dtype]:
+        """Column dtypes of a table-producing node's output."""
+        hit = cols_memo.get(node)
+        if hit is not None:
+            return hit
+        if isinstance(node, PH.PScan):
+            out = dtypes[node.table]
+        elif isinstance(node, (PH.PFilter, PH.Compact, PH.Exchange)):
+            out = cols(node.child)
+        elif isinstance(node, PH.PProject):
+            out = dict(cols(node.child))
+            out.update({name: _expr_dtype(e, cols(node.child))
+                        for name, e in node.cols})
+        elif isinstance(node, PH.PJoin):
+            build = cols(node.build)
+            out = dict(cols(node.probe))
+            out.update({new: build[src] for new, src in node.take})
+        elif isinstance(node, PH.PAttach):
+            out = dict(cols(node.child))
+            index = (node.source.index_name
+                     if isinstance(node.source, PH.PTopK) else None)
+            out.update({new: _I32 if src == index else _F32
+                        for new, src in node.cols})
+        else:
+            raise TypeError(f"{type(node).__name__} is no table")
+        cols_memo[node] = out
+        return out
+
+    def row_bytes(node) -> int:
+        return sum(d.itemsize for d in cols(node).values()) + _F32.itemsize
+
+    def visit(node) -> None:
+        nonlocal total
+        if node in seen:
+            return
+        seen.add(node)
+        if isinstance(node, PH.Exchange):
+            # a standalone table Exchange (the fused kinds are counted by
+            # the PAggregate / PTopK that runs them)
+            per_shard = (node.child.rows if node.kind == "broadcast"
+                         else node.capacity)
+            total += (n - 1) * per_shard * row_bytes(node.child)
+        elif isinstance(node, PH.PAggregate) and node.key is not None:
+            total += _merge_bytes(node, n)
+            while isinstance(node, (PH.PAggregate, PH.Exchange,
+                                    PH.PPartialAggregate)):
+                node = node.child          # the records the merge reads
+            visit(node)
+            return
+        elif isinstance(node, PH.PTopK) and node.dist == "candidates":
+            total += (n - 1) * node.k * (_F32.itemsize + _I32.itemsize)
+            visit(node.child.child)
+            return
+        for c in PH.children(node):
+            visit(c)
+
+    visit(phys.root)
+    return ExchangeWire(total, count)
+
+
+def _merge_bytes(node: PH.PAggregate, n: int) -> int:
+    """Wire bytes of a grouped PAggregate's fused merge (see
+    ``exchange_wire``)."""
+    C = stacked_width(tuple(a for a in node.aggs if not is_holistic(a[1][0])))
+    G, f = node.n_groups, _F32.itemsize
+    if node.merge in ("owner", "pushdown"):
+        republish = (n - 1) * -(-G // n) * C * f
+        return (n - 1) * node.child.capacity * (_I32.itemsize + C * f) \
+            + republish
+    if node.merge == "gather":
+        return (n - 1) * node.child.child.rows * (_I32.itemsize + C * f)
+    if node.merge in ("psum", "placed"):
+        return 2 * (n - 1) * G * C * f // n
+    if node.merge == "reduce_scatter":
+        return 2 * (n - 1) * (G + (-G % n)) * C * f // n
+    return 0                        # holistic: order statistics only
+
+
+def _expr_dtype(e: L.Expr, dtypes: Dict[str, jnp.dtype]) -> jnp.dtype:
+    """The dtype ``eval_expr`` gives ``e`` over columns of ``dtypes``,
+    by abstract evaluation (no device work)."""
+    shapes = {c: jax.ShapeDtypeStruct((1,), d) for c, d in dtypes.items()}
+    out = jax.eval_shape(lambda t: eval_expr(e, Table(t)), shapes)
+    return jnp.dtype(out.dtype)
+
+
 def _run_local(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
                record, tables, indexes):
     ex = _LocalExecutor(tables, ctx, indexes, profile, record)
@@ -1839,15 +1975,20 @@ class CompiledPlan:
     ``cache_key`` together with the dispatch wall time. Every dispatch
     path — serial execute_plan, the serving scheduler's whole-plan morsel
     tasks — goes through this one __call__, so the registry sees them
-    all."""
+    all.
+
+    ``wire`` is the plan's ``exchange_wire``, computed with the cache
+    entry; each ``plan.dispatch`` span carries it as ``exchange_bytes``
+    and ``exchanges``."""
 
     __slots__ = ("plan", "ctx", "fn", "index_specs", "physical",
-                 "cache_key", "record")
+                 "cache_key", "record", "wire")
 
     def __init__(self, plan: L.LogicalPlan, ctx: ExecutionContext, fn,
                  index_specs: Tuple[Tuple[str, str], ...],
                  physical: PH.PhysicalPlan, cache_key: Tuple = (),
-                 record: bool = False):
+                 record: bool = False,
+                 wire: ExchangeWire = ExchangeWire(0, 0)):
         self.plan = plan
         self.ctx = ctx
         self.fn = fn
@@ -1855,6 +1996,7 @@ class CompiledPlan:
         self.physical = physical
         self.cache_key = cache_key
         self.record = record
+        self.wire = wire
 
     def __call__(self, tables) -> Dict[str, jax.Array]:
         # the tracing flag is read HERE, per dispatch — it is deliberately
@@ -1872,7 +2014,8 @@ class CompiledPlan:
             "plan.dispatch", "plan", t0, time.perf_counter(),
             trace_id=tracing.current_trace_id(), pid="plan",
             plan=self.plan.name, key=hash(self.cache_key),
-            recorded=self.record)
+            recorded=self.record, exchange_bytes=self.wire.bytes,
+            exchanges=self.wire.exchanges)
         return out
 
     def _indexes(self, tables) -> Dict[str, Tuple[jax.Array, jax.Array]]:
@@ -1914,8 +2057,9 @@ def compile_plan(plan: L.LogicalPlan, tables,
     snapshotted ONCE: it keys the cache AND parameterizes the lowering, so
     a concurrent recalibration can never plan under the new constants but
     cache under the old key. The cache VALUE is the (physical plan, jitted
-    executable) pair — the physical tree is the product, the jit its
-    interpretation."""
+    executable, wire volume) triple — the physical tree is the product,
+    the jit its interpretation, ``exchange_wire`` its Exchanges' bytes
+    (outside the key and the jit)."""
     ctx = ctx or ExecutionContext()
     profile = current_cost_profile()
     record = telemetry.telemetry_enabled()
@@ -1929,7 +2073,7 @@ def compile_plan(plan: L.LogicalPlan, tables,
         L.validate(plan)     # fail fast (and once) instead of mid-trace
         phys = lower(plan, ctx, _true_rows(tables), profile)
         fn = _jit_plan(plan, phys, ctx, profile, record)
-        entry = (phys, fn)
+        entry = (phys, fn, exchange_wire(phys, tables))
         _PLAN_CACHE.put(key, entry)
         if traced:
             # lowering + jit construction, which a cache hit amortizes
@@ -1939,9 +2083,9 @@ def compile_plan(plan: L.LogicalPlan, tables,
                 plan=plan.name, key=hash(key))
     elif record:
         entry = _maybe_replan(key, entry, plan, ctx, profile, tables)
-    phys, fn = entry
+    phys, fn, wire = entry
     return CompiledPlan(plan, ctx, fn, required_indexes(plan.root), phys,
-                        key, record)
+                        key, record, wire)
 
 
 def _maybe_replan(key, entry, plan, ctx, profile, tables):
@@ -1961,7 +2105,7 @@ def _maybe_replan(key, entry, plan, ctx, profile, tables):
     if phys == entry[0]:
         return entry
     fn = _jit_plan(plan, phys, ctx, profile, True)
-    entry = (phys, fn)
+    entry = (phys, fn, exchange_wire(phys, tables))
     _PLAN_CACHE.put(key, entry)
     reg.note_replanned(key, phys)
     return entry

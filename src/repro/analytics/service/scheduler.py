@@ -165,9 +165,11 @@ class QueryTask:
         t0 = time.perf_counter()
         out = self.morsel_fn(self.tables, m.lo, length=m.length,
                              pool=pool_id)
+        # morsel tasks are split only without a mesh: nothing on the wire
         tracing.tracer().add_complete(
             "plan.dispatch", "plan", t0, time.perf_counter(),
-            trace_id=self.trace_id, pid=f"pool{pool_id}", seq=m.seq)
+            trace_id=self.trace_id, pid=f"pool{pool_id}", seq=m.seq,
+            exchange_bytes=0, exchanges=0)
         return out
 
     def _ready(self, out, pool_id: Optional[int] = None):

@@ -16,7 +16,10 @@ dispatch id for plan-level work) through every phase:
   morsel.run        one morsel on one pool's worker (pid=pool, tid=worker)
   morsel.steal      instant: a pool stole the tail of another's backlog
   plan.dispatch     one executable call up to its return: join-index
-                    lookups and the enqueue, not the device's work
+                    lookups and the enqueue, not the device's work; args
+                    ``exchange_bytes`` and ``exchanges``, the plan's
+                    inter-chip wire volume (planner.exchange_wire; 0
+                    without a mesh)
   plan.device_wait  block_until_ready on what a plan.dispatch enqueued
   merge.partials    morsel-order partial merge (QueryTask._finish)
   result.deliver    terminal-result fan-out (_record)
