@@ -53,7 +53,8 @@ import jax.numpy as jnp
 from repro.analytics.hashing import pad_partitions, partition_of
 from repro.analytics.plan import is_holistic, parse_quantile
 from repro.kernels.hash_aggregate import hash_aggregate
-from repro.kernels.hash_aggregate.kernel import TILE
+from repro.kernels.hash_aggregate.kernel import (STEP_TILES, TILE,
+                                                 padded_tiles)
 from repro.kernels.join_probe import join_probe
 
 # Largest key domain aggregated with full-width per-chunk tables (the
@@ -532,20 +533,29 @@ def _tile_fold(col: jax.Array, pad: int) -> jax.Array:
     return jnp.pad(col, (0, pad)).reshape(-1, 8, 128)
 
 
+def dense_layout(n_rows: int) -> Tuple[int, int]:
+    """(chunks, tiles per chunk) of the dense aggregate: at least 8 chunks
+    of equal grid steps (``padded_tiles``, which adds fewer than
+    STEP_TILES tiles), each under F32_EXACT_COUNT rows; one chunk below 8
+    tiles of rows."""
+    n_chunks = max(8, -(-n_rows // (F32_EXACT_COUNT - STEP_TILES * TILE))) \
+        if n_rows >= 8 * TILE else 1
+    return n_chunks, padded_tiles(-(-n_rows // (n_chunks * TILE)))
+
+
 def _fused_dense(keys: jax.Array, cols: Sequence[jax.Array], n_groups: int,
                  *, mode: Optional[str]) -> jax.Array:
     """Small key domain: positional chunking, full-width tables, no sort.
 
-    Rows are split into chunks by position; each chunk's (C, n_bins) table
-    covers every group, so the result is the exact sum of chunk tables —
-    no partitioning pass, no overflow possible. Padding rows carry zero
-    values, so their bin placement is irrelevant. A chunk holds fewer than
-    F32_EXACT_COUNT rows, so its counts are exact and add up in int32."""
+    Rows are split into chunks by position (``dense_layout``); each
+    chunk's (C, n_bins) table covers every group, so the result is the
+    exact sum of chunk tables — no partitioning pass, no overflow
+    possible. Padding rows carry zero values, so their bin placement is
+    irrelevant. A chunk holds fewer than F32_EXACT_COUNT rows, so its
+    counts are exact and add up in int32."""
     N = keys.shape[0]
     bins = max(128, -(-n_groups // 128) * 128)
-    n_chunks = max(8, -(-N // (F32_EXACT_COUNT - TILE))) \
-        if N >= 8 * TILE else 1
-    chunk_tiles = -(-N // (n_chunks * TILE))
+    n_chunks, chunk_tiles = dense_layout(N)
     pad = n_chunks * chunk_tiles * TILE - N
     table = hash_aggregate(_tile_fold(keys, pad),
                            [_tile_fold(c, pad) for c in cols],
@@ -581,7 +591,9 @@ def _fused_partitioned(keys: jax.Array, cols: Sequence[jax.Array],
     is counted and returned, as in aggregate.count_partitioned. The
     layout's sizes come from ``partition_layout``; a partition's slots are
     whole 1024-record tiles, so each row of the (P, slots) layout is a run
-    of the kernel's (R, 8, 128) fold."""
+    of the kernel's (R, 8, 128) fold. The kernel sweeps the largest number
+    of tiles per grid step that divides a partition's (``step_tiles``), so
+    the layout and its overflow do not depend on the step."""
     C = len(cols)
     n_partitions, range_size, bins, pad_t = partition_layout(
         keys.shape[0], n_groups, n_partitions, capacity_factor, TILE)

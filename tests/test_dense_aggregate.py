@@ -10,9 +10,12 @@ import pytest
 
 from repro.analytics import planner, tpch
 from repro.analytics.aggregate import count_partitioned
-from repro.analytics.columnar import Table, stacked_columns, stacked_group_sums
+from repro.analytics.columnar import (F32_EXACT_COUNT, Table, dense_layout,
+                                     stacked_columns, stacked_group_sums)
 from repro.analytics.engine import (merge_morsel_partials, morsel_group_sums,
                                     morsel_slices)
+from repro.kernels.hash_aggregate.kernel import (_UNROLL, STEP_TILES, TILE,
+                                                 step_tiles)
 
 AGGS = {"s0": ("sum", "v0"), "s1": ("sum", "v1"), "s2": ("sum", "v2"),
         "s3": ("sum", "v3"), "c": ("count", "v0")}
@@ -46,11 +49,14 @@ def _check(got, want, xla):
 
 
 @pytest.mark.parametrize("C", [1, 5])
-@pytest.mark.parametrize("n", [1, 1023, 1025, 8 * 1024 + 1, 100_003])
+@pytest.mark.parametrize("n", [1, 1023, 1025, 8 * 1024 + 1, 100_003,
+                               8 * STEP_TILES * TILE - 5,
+                               8 * (STEP_TILES + 9) * TILE + 7])
 def test_dense_sums_match_xla_and_oracle(rng, n, C):
-    """Ragged N around the 1024-record tile and the 8-chunk split: the
-    folded columns' padding adds nothing, masked rows vanish, counts are
-    exact."""
+    """Ragged N around the 1024-record tile, the 8-chunk split and the
+    grid step (chunks of 13, STEP_TILES and 48 tiles: 42 rounded up to 2
+    steps of 24): the folded columns' padding adds nothing, masked rows
+    vanish, counts are exact."""
     n_groups = 6
     aggs = dict(list(AGGS.items())[:C - 1] + [("c", ("count", "v0"))])
     t = _table(rng, n, n_groups, max(C - 1, 1))
@@ -63,10 +69,12 @@ def test_dense_sums_match_xla_and_oracle(rng, n, C):
     _check(got, _oracle(t, "k", n_groups, src), xla)
 
 
-@pytest.mark.parametrize("n", [1025, 30_001])
+@pytest.mark.parametrize("n", [1025, 30_001, 32_000, 37_888, 40_000,
+                               65_000])
 def test_partitioned_sums_match_xla_and_oracle(rng, n):
     """Range-partitioned layout: each partition's padded slots fold into
-    whole tiles of the kernel's operands."""
+    whole tiles of the kernel's operands (1, 30, 32, 37, 40 and 64 tiles:
+    steps of all, all, STEP_TILES, 1, 20 and STEP_TILES tiles)."""
     n_groups = 5000
     t = _table(rng, n, n_groups, 2)
     aggs = {"s0": ("sum", "v0"), "s1": ("sum", "v1")}
@@ -77,6 +85,28 @@ def test_partitioned_sums_match_xla_and_oracle(rng, n):
     xla, _ = stacked_group_sums(keys, cols, n_groups, layout="xla")
     assert int(ovf) == 0
     _check(got, _oracle(t, "k", n_groups, src), xla)
+
+
+@pytest.mark.parametrize("n", [1, 8 * TILE - 1, 8 * TILE, 8 * 33 * TILE,
+                               400_000, 60_000_000,
+                               8 * (F32_EXACT_COUNT - STEP_TILES * TILE),
+                               8 * (F32_EXACT_COUNT - STEP_TILES * TILE) + 1,
+                               600_000_000, 6_000_000_000])
+def test_dense_layout_whole_steps_and_exact_counts(n):
+    """Every chunk splits into no more default steps than
+    ceil(tiles / STEP_TILES), padded by fewer than min(STEP_TILES,
+    steps * _UNROLL) tiles (33 tiles pad to 40, not 64), and holds fewer
+    than F32_EXACT_COUNT rows, padding included. At SF 10 (60M rows) q1's
+    chunks are 7328 tiles (7325 rounded up to 229 steps of 32)."""
+    chunks, tiles = dense_layout(n)
+    need = -(-n // (chunks * TILE))
+    steps = -(-need // STEP_TILES)
+    assert 0 <= tiles - need < min(STEP_TILES, steps * _UNROLL)
+    assert tiles // step_tiles(tiles) <= steps
+    assert tiles * TILE < F32_EXACT_COUNT
+    assert chunks == 1 if n < 8 * TILE else chunks >= 8
+    if n == 60_000_000:
+        assert (chunks, tiles) == (8, 7328)
 
 
 def test_morsel_dense_partials_merge_to_oracle(rng):

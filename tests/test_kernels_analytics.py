@@ -6,6 +6,9 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels.hash_aggregate import hash_aggregate
+from repro.kernels.hash_aggregate.kernel import (STEP_TILES,
+                                                 hash_aggregate_pallas,
+                                                 padded_tiles, step_tiles)
 from repro.kernels.hash_aggregate.ref import hash_aggregate_ref
 from repro.kernels.join_probe import join_probe
 from repro.kernels.join_probe.ref import join_probe_ref
@@ -112,38 +115,101 @@ def _part_sums(ids, cols, n_parts, n_bins):
     return out
 
 
+def _tiles(block):
+    """Tiles per grid step for ``block`` records; None: the default, the
+    kernel's ``step_tiles`` of the part."""
+    return None if block is None else block // 1024
+
+
 @pytest.mark.parametrize("P,T,bins,block", [(2, 2048, 128, 1024),
                                             (4, 4096, 512, 2048),
-                                            (1, 3072, 256, 1024)])
+                                            (1, 3072, 256, 1024),
+                                            (2, 2048, 2048, 1024),
+                                            (2, 4096, 128, 2048),
+                                            (1, 8192, 2048, 8192),
+                                            (2, 2 * STEP_TILES * 1024, 1024,
+                                             None),
+                                            (2, 2 * STEP_TILES * 1024, 128,
+                                             None),
+                                            (1, STEP_TILES * 1024, 2048,
+                                             None)])
 def test_hash_aggregate_interpret(rng, P, T, bins, block):
     """One measure column, P contiguous parts of T records, ``block``
-    records per grid step."""
+    records per grid step (None: the default). Sums reach ~256 (512
+    uniform values a bin), where f32 order of addition moves the last
+    bits: the tolerance has a relative part."""
     ids = rng.randint(0, bins, P * T).astype(np.int32)
     vals = rng.rand(P * T).astype(np.float32)
     ref = hash_aggregate_ref(_fold(ids), [_fold(vals)], n_parts=P,
                              n_bins=bins)
-    got = hash_aggregate(_fold(ids), [_fold(vals)], n_parts=P, n_bins=bins,
-                         tiles=block // 1024, mode="interpret")
+    got = hash_aggregate_pallas(_fold(ids), [_fold(vals)], n_parts=P,
+                                n_bins=bins, tiles=_tiles(block),
+                                interpret=True)
     assert got.shape == (P, 1, bins)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4,
+                               rtol=1e-6)
     np.testing.assert_allclose(np.asarray(got),
-                               _part_sums(ids, [vals], P, bins), atol=1e-4)
+                               _part_sums(ids, [vals], P, bins), atol=1e-4,
+                               rtol=1e-6)
 
 
 @pytest.mark.parametrize("P,T,bins,C,block", [(2, 2048, 128, 3, 1024),
                                               (4, 4096, 256, 7, 2048),
-                                              (1, 1024, 128, 1, 1024)])
+                                              (1, 1024, 128, 1, 1024),
+                                              (2, 4096, 2048, 2, 2048),
+                                              (1, 8192, 128, 5, 8192),
+                                              (2, 2 * STEP_TILES * 1024, 128,
+                                               5, None),
+                                              (1, STEP_TILES * 1024, 2048,
+                                               2, None),
+                                              (3, 5 * 1024, 128, 5, None)])
 def test_hash_aggregate_multi_interpret(rng, P, T, bins, C, block):
-    """Fused multi-aggregate kernel vs oracles, incl. the C=1 edge."""
+    """Fused multi-aggregate kernel vs oracles, incl. the C=1 edge; the
+    default steps hold STEP_TILES tiles, or all of a short part."""
     ids = rng.randint(0, bins, P * T).astype(np.int32)
     cols = [rng.randn(P * T).astype(np.float32) for _ in range(C)]
     ref = hash_aggregate_ref(_fold(ids), [_fold(v) for v in cols],
                              n_parts=P, n_bins=bins)
-    got = hash_aggregate(_fold(ids), [_fold(v) for v in cols], n_parts=P,
-                         n_bins=bins, tiles=block // 1024, mode="interpret")
+    got = hash_aggregate_pallas(_fold(ids), [_fold(v) for v in cols],
+                                n_parts=P, n_bins=bins, tiles=_tiles(block),
+                                interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
     np.testing.assert_allclose(np.asarray(got),
                                _part_sums(ids, cols, P, bins), atol=1e-4)
+
+
+@pytest.mark.parametrize("R,P,tiles", [(6, 2, 2), (6, 4, None),
+                                        (8, 2, 3)])
+def test_hash_aggregate_rejects_ragged_steps(R, P, tiles):
+    """R tiles must split into P parts of whole ``tiles``-tile steps."""
+    ids = jnp.zeros((R, 8, 128), jnp.int32)
+    with pytest.raises(ValueError, match="not divisible"):
+        hash_aggregate_pallas(ids, [ids.astype(jnp.float32)], n_parts=P,
+                              n_bins=128, tiles=tiles, interpret=True)
+
+
+@pytest.mark.parametrize("part_tiles,want", [(1, 1), (7, 7), (16, 16),
+                                             (37, 1), (40, 20),
+                                             (7325, 25), (7328, 32)])
+def test_step_tiles_divides_the_part(part_tiles, want):
+    """The default step: the largest divisor of the part's tiles that is
+    at most STEP_TILES (q1 at SF 10: 7325 tiles a chunk, 7328 after
+    ``columnar.dense_layout`` rounds it)."""
+    assert STEP_TILES == 32
+    assert step_tiles(part_tiles) == want
+
+
+@pytest.mark.parametrize("part_tiles,want", [(1, 1), (7, 7), (32, 32),
+                                             (33, 40), (42, 48), (65, 72),
+                                             (7325, 7328)])
+def test_padded_tiles_splits_into_equal_steps(part_tiles, want):
+    """A part rounds up to ceil(tiles / STEP_TILES) equal steps of
+    _UNROLL-multiple tiles, adding fewer than STEP_TILES tiles, and the
+    kernel's default step divides it in no more steps."""
+    got = padded_tiles(part_tiles)
+    steps = -(-part_tiles // STEP_TILES)
+    assert got == want and got - part_tiles < STEP_TILES
+    assert got // step_tiles(got) <= steps
 
 
 def test_hash_aggregate_multi_matches_stacked_singles(rng):

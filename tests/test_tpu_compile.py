@@ -19,8 +19,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.analytics import planner, tpch
-from repro.analytics.columnar import join_layout, partition_layout
-from repro.kernels.hash_aggregate.kernel import TILE, hash_aggregate_pallas
+from repro.analytics.columnar import (DENSE_GROUP_LIMIT, dense_layout,
+                                     join_layout, partition_layout)
+from repro.kernels.hash_aggregate.kernel import (STEP_TILES, TILE,
+                                                 hash_aggregate_pallas,
+                                                 step_tiles)
 from repro.kernels.join_probe.kernel import join_probe_pallas
 from repro.kernels.radix_partition.kernel import block_histograms_pallas
 
@@ -74,10 +77,13 @@ def _folded(sharding, rows, dtype):
 
 
 def test_q1_dense_aggregate_compiles(one_chip):
-    """Q1's fused sweep: 8 positional chunks of SF 10 lineitem (60006400
-    records after padding), the weights and 4 weighted measures as
-    separate folded columns, 6 groups in a 128-bin table."""
-    P, rows, C = 8, 60_006_400, 5
+    """Q1's fused sweep: 8 positional chunks of SF 10 lineitem, each
+    7328 tiles (7325 rounded up to whole STEP_TILES-tile grid steps), the
+    weights and 4 weighted measures as separate folded columns, 6 groups
+    in a 128-bin table."""
+    (P, tiles), C = dense_layout(SF10_ROWS["lineitem"]), 5
+    assert (P, tiles) == (8, 7328) and step_tiles(tiles) == STEP_TILES
+    rows = P * tiles * TILE
     fn = functools.partial(hash_aggregate_pallas, n_parts=P, n_bins=128)
     hlo = _compiled_text(
         lambda ids, *cols: fn(ids, cols), _folded(one_chip, rows, jnp.int32),
@@ -85,11 +91,27 @@ def test_q1_dense_aggregate_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def test_widest_dense_aggregate_compiles(one_chip):
+    """The widest table the dense layout takes (DENSE_GROUP_LIMIT bins)
+    at q1's chunks and default steps: the loop's live one-hots grow with
+    the bins, so this is where a step's VMEM runs out first."""
+    (P, tiles), C = dense_layout(SF10_ROWS["lineitem"]), 5
+    fn = functools.partial(hash_aggregate_pallas, n_parts=P,
+                           n_bins=DENSE_GROUP_LIMIT)
+    hlo = _compiled_text(
+        lambda ids, *cols: fn(ids, cols),
+        _folded(one_chip, P * tiles * TILE, jnp.int32),
+        *[_folded(one_chip, P * tiles * TILE, jnp.float32)] * C)
+    assert "tpu_custom_call" in hlo
+
+
 def test_q18_partitioned_aggregate_compiles(one_chip):
     """Q18's per-order sums: 15M groups over 60M rows, range-partitioned
-    so each partition table stays within MAX_PARTITION_BINS."""
+    so each partition table stays within MAX_PARTITION_BINS; a grid step
+    sweeps a partition's 16 tiles."""
     P, _, bins, pad_t = partition_layout(SF10_ROWS["lineitem"],
                                          SF10_ROWS["orders"], 64, 2.0, TILE)
+    assert step_tiles(pad_t // TILE) == pad_t // TILE == 16
     fn = functools.partial(hash_aggregate_pallas, n_parts=P, n_bins=bins)
     hlo = _compiled_text(
         lambda ids, *cols: fn(ids, cols),
