@@ -1,27 +1,20 @@
 """Paper Figure 6: workload x allocator.
 
-Two real measurements:
- (a) device workloads W1/W2/W3 wall time with the partition-buffer tuning
-     the allocator implies (capacity factor = slack the allocator reserves;
-     partition count = arena granularity) — the device-side analogue of
-     "which allocator backs the hash tables";
- (b) the serving stack (continuous batching, paged KV) end-to-end with each
-     HOST allocator backing the page pool — tokens/s + admission stalls +
-     page-manager contention. This is where ptmalloc-vs-tbbmalloc shows up
-     on a TPU system for real.
+Device workloads W1/W2/W3 wall time with the partition-buffer tuning the
+allocator implies (capacity factor = slack the allocator reserves;
+partition count = arena granularity) — the device-side analogue of "which
+allocator backs the hash tables".
 """
 from __future__ import annotations
 
 from typing import List
 
-import jax
 import jax.numpy as jnp
 
 from benchmarks.common import Row, time_fn
 from repro.analytics.aggregate import count_partitioned, median_jit
 from repro.analytics.datasets import blanas_join, moving_cluster
 from repro.analytics.join import hash_join
-from repro.core.config import AllocatorKind
 
 
 def run() -> List[Row]:
@@ -31,7 +24,7 @@ def run() -> List[Row]:
     keys = jnp.asarray(ds.keys)
     vals = jnp.asarray(ds.vals)
 
-    # (a) device-side buffer tuning (bump=tight serial, slab=sized classes)
+    # device-side buffer tuning (bump=tight serial, slab=sized classes)
     tunings = {"bump_like": dict(n_partitions=1, capacity_factor=1.05),
                "arena_like": dict(n_partitions=16, capacity_factor=1.5),
                "slab_like": dict(n_partitions=64, capacity_factor=2.0)}
@@ -50,27 +43,4 @@ def run() -> List[Row]:
         us = time_fn(lambda kw=kw: hash_join(bk, bv, pk, mode="ref", **kw))
         rows.append((f"fig6_w3_{name}", us, str(kw)))
 
-    # (b) serving with each host allocator backing the KV page pool
-    from repro.configs.reduced import REDUCED
-    from repro.core.params import init_params
-    from repro.models.lm import LMModel
-    from repro.runtime import ContinuousBatcher, Request
-    arch = REDUCED["qwen2-0.5b"]
-    model = LMModel(arch, tp=1, remat="none")
-    params = init_params(model.schema(), jax.random.PRNGKey(0), jnp.float32)
-    import time as _time
-    for kind in AllocatorKind:
-        b = ContinuousBatcher(model, params, wave_slots=8, max_len=64,
-                              page_tokens=8, n_pages=48, allocator=kind)
-        for i in range(24):
-            b.submit(Request(req_id=i, prompt_len=6, max_new_tokens=8))
-        t0 = _time.perf_counter()
-        stats = b.run(max_steps=600)
-        dt = _time.perf_counter() - t0
-        st = b.kv.allocator_stats
-        rows.append((f"fig6_serve_{kind.value}", dt * 1e6 / max(stats.steps, 1),
-                     f"tokens/s={stats.tokens_out/dt:.0f};"
-                     f"stalls={stats.admission_stalls};"
-                     f"contention={st.contentions};"
-                     f"util={stats.lane_utilization:.2f}"))
     return rows

@@ -4,7 +4,7 @@ Prints ``name,us_per_call,derived`` CSV (assignment format). Modules:
   fig2   allocator microbenchmark (scalability + memory overhead)
   fig3/4 thread placement (layouts; sparse/dense undersubscription)
   fig5   placement policies x auto-rebalance (8-device mesh, measured)
-  fig6   workload x allocator (device buffers + serving page pool)
+  fig6   workload x allocator (device buffers)
   fig7   index nested-loop join (three index kinds)
   fig7_dist  distributed join: broadcast vs key-partitioned, plus the
          distributed TopK lowerings (replicated vs candidate-exchange)
@@ -23,7 +23,6 @@ Prints ``name,us_per_call,derived`` CSV (assignment format). Modules:
          telemetry; reports drifting (node, stat) entries (absolute
          floor >= 1 — the detector must fire) and the max
          observed/estimated deviation ratio per Decision kind
-  roofline  the dry-run (arch x shape x mesh) table
 """
 import argparse
 import json
@@ -54,7 +53,7 @@ def main() -> None:
                             fig5_placement_policies,
                             fig6_workload_allocators, fig7_index_join,
                             fig8_fig9_tpch, fig_drift,
-                            fig_service_throughput, roofline_table)
+                            fig_service_throughput)
     from types import SimpleNamespace
     modules = [
         ("fig2", fig2_allocator_microbench),
@@ -70,7 +69,6 @@ def main() -> None:
         ("fig_service_morsel",
          SimpleNamespace(run=fig_service_throughput.run_morsel)),
         ("fig_drift", fig_drift),
-        ("roofline", roofline_table),
     ]
     if args.skip_slow:
         # the subprocess-mesh figures

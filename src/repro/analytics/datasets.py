@@ -2,9 +2,7 @@
 
 Paper defaults: 100M records, group-by cardinality 1M for aggregations;
 join tables 16M (build) : 256M (probe) — the Blanas'11 decision-support
-ratio. All generators are numpy (host side — this is the data pipeline's
-source, sharded across hosts by ``repro.data.pipeline``), deterministic
-under a seed.
+ratio. All generators are numpy (host side), deterministic under a seed.
 """
 from __future__ import annotations
 

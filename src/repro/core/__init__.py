@@ -1,54 +1,9 @@
-"""Core: configs, placement policies, topology model, partitioning engine.
+"""Core: placement policies, mesh layouts, the topology model.
 
 This package holds the paper's primary contribution adapted to TPU: the
-application-agnostic placement system (memory placement policies, mesh
-layouts / thread placement, allocator + OS-config knobs) that every workload
-in the framework — analytics operators and LM architectures alike — runs
-under without code changes.
+placement system (memory placement policies, mesh layouts as the
+thread-placement analogue, allocator kinds) that the analytics engine's
+operators run under without code changes.
 """
-from repro.core.config import (
-    AllocatorKind,
-    ArchConfig,
-    AttentionKind,
-    HybridConfig,
-    LM_SHAPES,
-    MLAConfig,
-    MeshLayout,
-    MoEConfig,
-    OSConfig,
-    PaddedDims,
-    PlacementPolicy,
-    RWKVConfig,
-    RopeKind,
-    RunConfig,
-    ShapeConfig,
-    ShardingConfig,
-    StepKind,
-    TrainConfig,
-    pad_to,
-)
-from repro.core.params import (
-    ParamDef,
-    abstract_params,
-    axes_tree,
-    init_params,
-    param_bytes,
-    param_count,
-    pdef,
-    shapes_tree,
-)
-from repro.core.partitioning import (
-    DEFAULT_RULES,
-    policy_state_spec,
-    rules_with,
-    spec_for,
-    tree_shardings,
-    tree_specs,
-    validate_spec,
-)
-from repro.core.topology import (
-    HBM_BW,
-    ICI_LINK_BW,
-    PEAK_FLOPS_BF16,
-    TorusTopology,
-)
+from repro.core.config import AllocatorKind, MeshLayout, PlacementPolicy
+from repro.core.topology import ICI_LINK_BW, TorusTopology

@@ -10,23 +10,16 @@ SPARSE/DENSE/NONE thread-placement analogues are compared quantitatively
 (the CPU-backend HLO is placement-agnostic, so this model supplies the
 topology term the hardware would).
 
-Hardware constants (TPU v5e, per the assignment):
-  peak bf16 compute   197 TFLOP/s / chip
-  HBM bandwidth       819 GB/s / chip
+Hardware constants (TPU v5e):
   ICI link bandwidth  ~50 GB/s / link  (4 links/chip on a 2D torus)
   inter-pod (DCI)     modeled at 1/8 of an ICI link per chip pair
 """
 from __future__ import annotations
 
-import dataclasses
-import math
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Tuple
+from typing import Sequence
 
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
 ICI_LINK_BW = 50e9                # bytes/s per link
-ICI_LINKS_PER_CHIP = 4            # 2D torus: +/-x, +/-y
 DCI_BW = ICI_LINK_BW / 8          # inter-pod tier
 
 
@@ -141,19 +134,3 @@ COLLECTIVE_COSTS = {
         if len(group) > 1 else 0.0),
 }
 
-
-# ---------------------------------------------------------------------------
-# Simple aggregate roofline helpers (used by launch.dryrun / benchmarks)
-# ---------------------------------------------------------------------------
-def compute_seconds(total_flops: float, n_chips: int) -> float:
-    return total_flops / (n_chips * PEAK_FLOPS_BF16)
-
-
-def memory_seconds(total_bytes: float, n_chips: int) -> float:
-    return total_bytes / (n_chips * HBM_BW)
-
-
-def collective_seconds(total_bytes: float, n_chips: int,
-                       links_per_chip: float = ICI_LINKS_PER_CHIP) -> float:
-    """Flat assignment-mandated form: bytes / (chips x link_bw)."""
-    return total_bytes / (n_chips * ICI_LINK_BW)

@@ -1,5 +1,4 @@
-"""Device-arena memory management: allocators, paged KV cache, microbench."""
+"""Device-arena memory management: allocators and their microbenchmark."""
 from repro.memory.allocators import (Allocator, AllocStats, Block,
                                      make_allocator)
 from repro.memory.microbench import MicrobenchResult, run_microbench, sweep
-from repro.memory.paged_kv import PagedKVManager, gather_sequence
