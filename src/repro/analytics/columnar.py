@@ -43,9 +43,11 @@ skewed keys stay exact — or is counted and surfaced with residual=False).
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -187,22 +189,69 @@ def join_layout(n_fact: int, n_dim: int, n_partitions: int,
             _capacity(n_fact, P, capacity_factor, 1024))
 
 
+class JoinSide(NamedTuple):
+    """One side of the kernel join in its dense (P, cap) partition layout.
+
+    ``keys``: (P, cap) int32, -1 in empty slots. ``rows``: on the build
+    side the (P, cap) float32 row position of each slot (the probe's
+    payload); on the probe side the (n,) int32 slot of each row, P * cap
+    for a row beyond its partition's capacity. ``overflow``: the side's
+    rows beyond capacity."""
+    keys: jax.Array
+    rows: jax.Array
+    overflow: jax.Array
+
+
+@functools.partial(jax.jit, static_argnames=("n_partitions", "cap", "side"))
+def join_side_layout(keys: jax.Array, n_partitions: int, cap: int,
+                     side: str) -> JoinSide:
+    """``keys`` hash-partitioned (hashing.partition_of, so matching keys
+    co-partition) into the kernel join's (P, cap) layout, rows in their
+    original order within a partition. It depends on the key column
+    alone, so the planner's JoinIndexPool builds it once per base column
+    and feeds it to every plan that joins on that column."""
+    if side not in ("build", "probe"):
+        raise ValueError(f"unknown join side {side!r}")
+    P = n_partitions
+    keys = keys.astype(jnp.int32)
+    n = keys.shape[0]
+    part = partition_of(keys, P)
+    order = jnp.argsort(part, stable=True)
+    counts = jnp.bincount(part, length=P)
+    starts = jnp.cumsum(counts) - counts
+    if side == "build":
+        # the build side carries its own row positions as the probe payload
+        pkeys, rows, overflow = pad_partitions(
+            keys[order], order.astype(jnp.float32), starts, counts, P, cap)
+    else:
+        pkeys, _, overflow = pad_partitions(keys[order], [], starts, counts,
+                                            P, cap)
+        rank = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+        within = rank - starts[part]
+        rows = jnp.where(within < cap, part * cap + within, P * cap)
+    return JoinSide(pkeys, rows, overflow.astype(jnp.int32))
+
+
 def pkfk_join_kernel(fact: Table, dim: Table, fact_key: str, dim_key: str,
                      take: Mapping[str, str], *, n_partitions: int = 32,
                      capacity_factor: float = 2.0,
                      mode: Optional[str] = None,
-                     residual: bool = True
+                     residual: bool = True,
+                     layouts: Optional[Tuple[JoinSide, JoinSide]] = None
                      ) -> Tuple[Table, jax.Array]:
     """PK-FK join probed through the kernels/join_probe blocked compare.
 
-    Both sides are hash-partitioned (hashing.partition_of, so matching keys
-    co-partition) into dense (P, cap) layouts; the kernel matches each probe
-    slot against its partition's build tile and returns the matched build
-    ROW POSITION, through which the ``take`` columns (and the build-side
-    mask) are gathered. Keys must be non-negative (key -1 is the padding
-    sentinel).
+    Both sides are hash-partitioned into dense (P, cap) layouts
+    (``join_side_layout``); the kernel matches each probe slot against its
+    partition's build tile and returns the matched build ROW POSITION,
+    through which the ``take`` columns (and the build-side mask) are
+    gathered. Keys must be non-negative (key -1 is the padding sentinel).
 
     The partitions and their capacities come from ``join_layout``.
+    ``layouts`` is the (probe, build) pair of ``join_side_layout`` at
+    those sizes, built ahead (the planner pools them for base-column
+    keys); None builds both here.
 
     Rows beyond a partition's capacity — on either side — are dropped from
     the dense layouts and would degrade to join misses under key skew. With
@@ -224,30 +273,20 @@ def pkfk_join_kernel(fact: Table, dim: Table, fact_key: str, dim_key: str,
                          f"got fact={n_fact}, dim={n_dim}")
     P, build_cap, probe_cap = join_layout(n_fact, n_dim, n_partitions,
                                           capacity_factor)
-
-    def _layout(keys, payload, cap):
-        part = partition_of(keys, P)
-        order = jnp.argsort(part, stable=True)
-        counts = jnp.bincount(part, length=P)
-        starts = jnp.cumsum(counts) - counts
-        return pad_partitions(keys[order], payload[order], starts, counts,
-                              P, cap)
-
-    # build side carries its own row positions as the probe payload
-    bkeys, bpos, ovf_b = _layout(dk, jnp.arange(n_dim, dtype=jnp.float32),
-                                 build_cap)
-    pkeys, prow, ovf_p = _layout(fk, jnp.arange(n_fact, dtype=jnp.float32),
-                                 probe_cap)
-    vals, found = join_probe(bkeys, bpos, pkeys, mode=mode)
-    # scatter per-slot results back to original row order; padding slots
-    # (key -1) collide on a dummy row that is sliced off
-    slot_valid = (pkeys >= 0).reshape(-1)
-    rows = jnp.where(slot_valid, prow.reshape(-1).astype(jnp.int32), n_fact)
-    pos = (jnp.zeros((n_fact + 1,), jnp.int32)
-           .at[rows].set(vals.reshape(-1).astype(jnp.int32))[:n_fact])
-    found_r = (jnp.zeros((n_fact + 1,), jnp.bool_)
-               .at[rows].set(found.reshape(-1) & slot_valid)[:n_fact])
-    overflow = (ovf_b + ovf_p).astype(jnp.int32)
+    if layouts is None:
+        layouts = (join_side_layout(fk, P, probe_cap, "probe"),
+                   join_side_layout(dk, P, build_cap, "build"))
+    probe, build = layouts
+    if (probe.keys.shape != (P, probe_cap) or probe.rows.shape != (n_fact,)
+            or build.keys.shape != (P, build_cap)):
+        raise ValueError("join layouts do not match join_layout's sizes")
+    vals, found = join_probe(build.keys, build.rows, probe.keys, mode=mode)
+    # back to row order in one gather through each row's slot: the
+    # matched position, or -1 for a miss and for a row beyond capacity
+    packed = jnp.where(found, vals.astype(jnp.int32), -1).reshape(-1)
+    hit = packed.at[probe.rows].get(mode="fill", fill_value=-1)
+    pos, found_r = jnp.maximum(hit, 0), hit >= 0
+    overflow = probe.overflow + build.overflow
     if residual:
         # Residual pass: capacity overflow drops rows from the dense
         # layouts — a dropped probe row never reaches a slot, and a

@@ -64,10 +64,11 @@ equivalent passes over the input rows:
 
 Compiled plans live in a bounded LRU cache keyed by (logical plan
 structure, context key, table shape signature, cost profile); the cache
-VALUE is the (physical plan, jitted executable, wire volume) triple, so
-the physical tree is inspectable for every cached entry. Join build-side
-argsort indexes are pooled across calls keyed on column-array *identity*
-and enter the compiled plan as traced arguments.
+VALUE is the (physical plan, jitted executable, wire volume, kernel
+joins) tuple, so the physical tree is inspectable for every cached entry.
+Join build-side argsort indexes, and the kernel join's partition layouts
+of base-column keys, are pooled across calls keyed on column-array
+*identity* and enter the compiled plan as traced arguments.
 """
 from __future__ import annotations
 
@@ -90,8 +91,9 @@ from repro.analytics import plan as L
 from repro.analytics import telemetry
 from repro.analytics import tracing
 from repro.analytics.columnar import (DENSE_GROUP_LIMIT,
-                                      KERNEL_JOIN_MAX_ROWS, Table,
+                                      KERNEL_JOIN_MAX_ROWS, JoinSide, Table,
                                       finalize_stacked, group_aggregate,
+                                      join_layout, join_side_layout,
                                       pkfk_join, pkfk_join_kernel,
                                       segment_distinct, segment_median,
                                       segment_order_stat, segment_quantile,
@@ -617,12 +619,18 @@ class JoinIndexPool:
     never keeps a dropped dataset alive on device), computes the argsort
     ONCE eagerly, and feeds it to the compiled plan as a traced argument —
     so the index survives Table reconstruction and is shared by every
-    query/plan that joins through the same build column."""
+    query/plan that joins through the same build column.
+
+    ``layout`` does the same for the kernel join's per-side partition
+    layouts (``columnar.join_side_layout``); ``layout_builds`` counts
+    them as ``builds`` counts argsorts."""
 
     def __init__(self, maxsize: int = 256):
         self._lru = LRUCache(maxsize)
+        self._building: Dict[Tuple, threading.Lock] = {}
         self.builds = 0
         self.replicas = 0
+        self.layout_builds = 0
 
     def get(self, table: str, column: str, arr) -> Tuple[jax.Array, jax.Array]:
         key = (table, column, id(arr))
@@ -667,6 +675,33 @@ class JoinIndexPool:
             self._sweep_dead()
         return idx
 
+    def layout(self, table: str, column: str, arr, n_partitions: int,
+               cap: int, side: str) -> JoinSide:
+        """The kernel join's ``side`` layout of ``arr`` at (P, cap), keyed
+        as ``get`` is, plus (P, cap, side). The builds of one key are
+        serialized and double-checked, so plans compiled side by side
+        build each layout once (``layout_builds``); distinct layouts
+        build (and compile) side by side."""
+        key = (table, column, id(arr), "layout", n_partitions, cap, side)
+        hit = self._lru.get(key)
+        if hit is not None and hit[0]() is arr:
+            return hit[1]
+        with self._lru._lock:
+            lock = self._building.setdefault(key, threading.Lock())
+        with lock:
+            hit = self._lru.get(key)
+            if hit is None or hit[0]() is not arr:
+                hit = (weakref.ref(arr), join_side_layout(
+                    jnp.asarray(arr), n_partitions, cap, side))
+                with self._lru._lock:
+                    self._lru.put(key, hit)
+                    self.layout_builds += 1
+                    self._sweep_dead()
+        with self._lru._lock:
+            if self._building.get(key) is lock:
+                del self._building[key]
+        return hit[1]
+
     def _sweep_dead(self) -> None:
         with self._lru._lock:
             dead = [k for k, (ref, _) in self._lru._d.items()
@@ -681,6 +716,7 @@ class JoinIndexPool:
         self._lru.clear()
         self.builds = 0
         self.replicas = 0
+        self.layout_builds = 0
 
 
 _INDEX_POOL = JoinIndexPool()
@@ -824,6 +860,69 @@ def probe_split(phys: PH.PhysicalPlan) -> Optional[ProbeSplit]:
         return None
     return ProbeSplit(phys.root, phys.outputs, scan, node.child,
                       tuple(preludes), scan.rows)
+
+
+# ---------------------------------------------------------------------------
+# kernel-join layouts hoisted into the JoinIndexPool
+# ---------------------------------------------------------------------------
+class LayoutSpec(NamedTuple):
+    """One pooled kernel-join layout: ``side`` of ``table.column`` at
+    (``n_partitions``, ``cap``) (``JoinIndexPool.layout``)."""
+    table: str
+    column: str
+    n_partitions: int
+    cap: int
+    side: str
+
+    @property
+    def key(self) -> str:
+        return (f"{self.side}:{self.table}.{self.column}:"
+                f"{self.n_partitions}x{self.cap}")
+
+
+def pooled_layouts(node: PH.PJoin, ctx: "ExecutionContext"
+                   ) -> Optional[Tuple[LayoutSpec, LayoutSpec]]:
+    """(probe, build) layouts of a local kernel join whose keys both reach
+    it from a base scan (``_physical_base_scan``: the layout built from
+    the base column array is then the join's own), at ``join_layout``'s
+    sizes for the scans' static rows; None for any other join, which
+    builds its layouts in the plan."""
+    if node.strategy != "kernel" or node.dist is not None:
+        return None
+    probe = _physical_base_scan(node.probe, node.probe_key)
+    build = _physical_base_scan(node.build, node.build_key)
+    if probe is None or build is None:
+        return None
+    P, build_cap, probe_cap = join_layout(probe.rows, build.rows,
+                                          ctx.n_partitions,
+                                          ctx.capacity_factor)
+    return (LayoutSpec(probe.table, node.probe_key, P, probe_cap, "probe"),
+            LayoutSpec(build.table, node.build_key, P, build_cap, "build"))
+
+
+class KernelJoins(NamedTuple):
+    """A plan's kernel joins, static per plan-cache entry: the pooled
+    layouts the plan takes as arguments, and how many joins run on them
+    (``hoisted``) or build their layouts in the plan (``inline``)."""
+    layouts: Tuple[LayoutSpec, ...] = ()
+    hoisted: int = 0
+    inline: int = 0
+
+
+def kernel_joins(phys: PH.PhysicalPlan,
+                 ctx: "ExecutionContext") -> KernelJoins:
+    specs: List[LayoutSpec] = []
+    hoisted = inline = 0
+    for node in PH.walk_unique(phys.root):
+        if not (isinstance(node, PH.PJoin) and node.strategy == "kernel"):
+            continue
+        pair = pooled_layouts(node, ctx)
+        if pair is None:
+            inline += 1
+            continue
+        hoisted += 1
+        specs.extend(sp for sp in pair if sp not in specs)
+    return KernelJoins(tuple(specs), hoisted, inline)
 
 
 # ---------------------------------------------------------------------------
@@ -1215,10 +1314,12 @@ class _LocalExecutor:
 
     def __init__(self, tables, ctx: ExecutionContext, indexes,
                  profile: Optional[CostProfile] = None,
-                 record: bool = False):
+                 record: bool = False,
+                 layouts: Optional[Dict[str, JoinSide]] = None):
         self.tables = tables
         self.ctx = ctx
         self.indexes = indexes           # {"table.column": (order, sk)}
+        self.layouts = layouts or {}     # {LayoutSpec.key: JoinSide}
         self.profile = profile
         # fitted partitioned-layout capacity (profile) falls back to ctx
         self.agg_cf = ((profile.partition_capacity_factor
@@ -1293,11 +1394,16 @@ class _LocalExecutor:
         probe = self.run(node.probe)
         build = self.run(node.build)
         if node.strategy == "kernel":
+            pair = pooled_layouts(node, self.ctx)
+            layouts = None
+            if pair is not None and all(sp.key in self.layouts
+                                        for sp in pair):
+                layouts = tuple(self.layouts[sp.key] for sp in pair)
             joined, ovf = pkfk_join_kernel(
                 probe, build, node.probe_key, node.build_key,
                 dict(node.take), mode=self.ctx.mode,
                 n_partitions=self.ctx.n_partitions,
-                capacity_factor=self.ctx.capacity_factor)
+                capacity_factor=self.ctx.capacity_factor, layouts=layouts)
             self.overflow = self.overflow + ovf
         else:
             joined = pkfk_join(probe, build, node.probe_key,
@@ -1909,14 +2015,15 @@ def _expr_dtype(e: L.Expr, dtypes: Dict[str, jnp.dtype]) -> jnp.dtype:
 
 
 def _run_local(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
-               record, tables, indexes):
-    ex = _LocalExecutor(tables, ctx, indexes, profile, record)
+               record, tables, indexes, layouts):
+    ex = _LocalExecutor(tables, ctx, indexes, profile, record, layouts)
     return ex.execute(phys)
 
 
 def _run_distributed(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
-                     record, tables, indexes):
-    del indexes          # full-table indexes don't survive the row padding
+                     record, tables, indexes, layouts):
+    # full-table indexes and layouts don't survive the row padding
+    del indexes, layouts
     mesh, axis = ctx.mesh, ctx.axis
     n = mesh.shape[axis]
     rows = _true_rows(tables)
@@ -1940,19 +2047,21 @@ def _run_distributed(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
 
 
 def _run_plan(phys: PH.PhysicalPlan, ctx: ExecutionContext, profile,
-              record, tables, indexes):
-    if ctx.mesh is None:
-        return _run_local(phys, ctx, profile, record, tables, indexes)
-    return _run_distributed(phys, ctx, profile, record, tables, indexes)
+              record, tables, indexes, layouts):
+    run = _run_local if ctx.mesh is None else _run_distributed
+    return run(phys, ctx, profile, record, tables, indexes, layouts)
 
 
 def _jit_plan(plan: L.LogicalPlan, phys: PH.PhysicalPlan,
               ctx: ExecutionContext, profile, record: bool):
     """The plan's executable, as a function named ``plan_<name>``: its
     XLA module reads ``jit_plan_<name>`` in a profile (a bare partial
-    would read ``jit__unknown``)."""
-    def run(tables, indexes):
-        return _run_plan(phys, ctx, profile, record, tables, indexes)
+    would read ``jit__unknown``). ``layouts`` are the pooled kernel-join
+    layouts by ``LayoutSpec.key``; a join whose layouts are absent builds
+    them in the plan."""
+    def run(tables, indexes, layouts=None):
+        return _run_plan(phys, ctx, profile, record, tables, indexes,
+                         layouts)
     run.__name__ = run.__qualname__ = (f"plan_{plan.name}" if plan.name
                                        else "plan")
     return jax.jit(run)
@@ -1979,16 +2088,20 @@ class CompiledPlan:
 
     ``wire`` is the plan's ``exchange_wire``, computed with the cache
     entry; each ``plan.dispatch`` span carries it as ``exchange_bytes``
-    and ``exchanges``."""
+    and ``exchanges``. ``joins`` is the plan's ``kernel_joins``: the
+    layouts each call takes from the JoinIndexPool, and the counts each
+    ``plan.dispatch`` span carries as ``hoisted_joins`` and
+    ``inline_joins``."""
 
     __slots__ = ("plan", "ctx", "fn", "index_specs", "physical",
-                 "cache_key", "record", "wire")
+                 "cache_key", "record", "wire", "joins")
 
     def __init__(self, plan: L.LogicalPlan, ctx: ExecutionContext, fn,
                  index_specs: Tuple[Tuple[str, str], ...],
                  physical: PH.PhysicalPlan, cache_key: Tuple = (),
                  record: bool = False,
-                 wire: ExchangeWire = ExchangeWire(0, 0)):
+                 wire: ExchangeWire = ExchangeWire(0, 0),
+                 joins: KernelJoins = KernelJoins()):
         self.plan = plan
         self.ctx = ctx
         self.fn = fn
@@ -1997,6 +2110,7 @@ class CompiledPlan:
         self.cache_key = cache_key
         self.record = record
         self.wire = wire
+        self.joins = joins
 
     def __call__(self, tables) -> Dict[str, jax.Array]:
         # the tracing flag is read HERE, per dispatch — it is deliberately
@@ -2015,28 +2129,37 @@ class CompiledPlan:
             trace_id=tracing.current_trace_id(), pid="plan",
             plan=self.plan.name, key=hash(self.cache_key),
             recorded=self.record, exchange_bytes=self.wire.bytes,
-            exchanges=self.wire.exchanges)
+            exchanges=self.wire.exchanges, hoisted_joins=self.joins.hoisted,
+            inline_joins=self.joins.inline)
         return out
 
-    def _indexes(self, tables) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+    def _indexes(self, tables) -> Tuple[Dict[str, Tuple[jax.Array,
+                                                        jax.Array]],
+                                        Dict[str, JoinSide]]:
+        """(build argsorts, kernel-join layouts) from the JoinIndexPool;
+        a mesh plan takes neither."""
         if self.ctx.mesh is not None:
-            return {}
-        return {f"{t}.{c}": _INDEX_POOL.get(t, c, tables[t][c])
-                for t, c in self.index_specs}
+            return {}, {}
+        return ({f"{t}.{c}": _INDEX_POOL.get(t, c, tables[t][c])
+                 for t, c in self.index_specs},
+                {sp.key: _INDEX_POOL.layout(sp.table, sp.column,
+                                            tables[sp.table][sp.column],
+                                            sp.n_partitions, sp.cap, sp.side)
+                 for sp in self.joins.layouts})
 
     def lower(self, tables):
-        """The executable's lowering for ``tables``, join indexes built as a
-        dispatch builds them: ``.compile()`` on it compiles the plan ahead
-        of its first run (into the persistent compile cache, where that
-        is on)."""
-        return self.fn.lower(tables, self._indexes(tables))
+        """The executable's lowering for ``tables``, join indexes and
+        layouts built as a dispatch builds them: ``.compile()`` on it
+        compiles the plan ahead of its first run (into the persistent
+        compile cache, where that is on)."""
+        return self.fn.lower(tables, *self._indexes(tables))
 
     def _execute(self, tables) -> Dict[str, jax.Array]:
-        indexes = self._indexes(tables)
+        indexes, layouts = self._indexes(tables)
         if not self.record:
-            return self.fn(tables, indexes)
+            return self.fn(tables, indexes, layouts)
         t0 = time.perf_counter()
-        out = dict(self.fn(tables, indexes))
+        out = dict(self.fn(tables, indexes, layouts))
         stats = out.pop("_stats", None)
         if stats is not None:
             concrete = {int(i): {k: int(v) for k, v in
@@ -2057,8 +2180,9 @@ def compile_plan(plan: L.LogicalPlan, tables,
     snapshotted ONCE: it keys the cache AND parameterizes the lowering, so
     a concurrent recalibration can never plan under the new constants but
     cache under the old key. The cache VALUE is the (physical plan, jitted
-    executable, wire volume) triple — the physical tree is the product,
-    the jit its interpretation, ``exchange_wire`` its Exchanges' bytes
+    executable, wire volume, kernel joins) tuple — the physical tree is
+    the product, the jit its interpretation, ``exchange_wire`` its
+    Exchanges' bytes and ``kernel_joins`` its pooled join layouts
     (outside the key and the jit)."""
     ctx = ctx or ExecutionContext()
     profile = current_cost_profile()
@@ -2073,7 +2197,8 @@ def compile_plan(plan: L.LogicalPlan, tables,
         L.validate(plan)     # fail fast (and once) instead of mid-trace
         phys = lower(plan, ctx, _true_rows(tables), profile)
         fn = _jit_plan(plan, phys, ctx, profile, record)
-        entry = (phys, fn, exchange_wire(phys, tables))
+        entry = (phys, fn, exchange_wire(phys, tables),
+                 kernel_joins(phys, ctx))
         _PLAN_CACHE.put(key, entry)
         if traced:
             # lowering + jit construction, which a cache hit amortizes
@@ -2083,9 +2208,9 @@ def compile_plan(plan: L.LogicalPlan, tables,
                 plan=plan.name, key=hash(key))
     elif record:
         entry = _maybe_replan(key, entry, plan, ctx, profile, tables)
-    phys, fn, wire = entry
+    phys, fn, wire, joins = entry
     return CompiledPlan(plan, ctx, fn, required_indexes(plan.root), phys,
-                        key, record, wire)
+                        key, record, wire, joins)
 
 
 def _maybe_replan(key, entry, plan, ctx, profile, tables):
@@ -2105,7 +2230,7 @@ def _maybe_replan(key, entry, plan, ctx, profile, tables):
     if phys == entry[0]:
         return entry
     fn = _jit_plan(plan, phys, ctx, profile, True)
-    entry = (phys, fn, exchange_wire(phys, tables))
+    entry = (phys, fn, exchange_wire(phys, tables), kernel_joins(phys, ctx))
     _PLAN_CACHE.put(key, entry)
     reg.note_replanned(key, phys)
     return entry

@@ -165,11 +165,13 @@ class QueryTask:
         t0 = time.perf_counter()
         out = self.morsel_fn(self.tables, m.lo, length=m.length,
                              pool=pool_id)
-        # morsel tasks are split only without a mesh: nothing on the wire
+        # morsel tasks are split only without a mesh: nothing on the
+        # wire; a morsel runs only the split pipeline, whose joins are
+        # all sorted: no kernel join
         tracing.tracer().add_complete(
             "plan.dispatch", "plan", t0, time.perf_counter(),
             trace_id=self.trace_id, pid=f"pool{pool_id}", seq=m.seq,
-            exchange_bytes=0, exchanges=0)
+            exchange_bytes=0, exchanges=0, hoisted_joins=0, inline_joins=0)
         return out
 
     def _ready(self, out, pool_id: Optional[int] = None):

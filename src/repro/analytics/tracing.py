@@ -19,7 +19,10 @@ dispatch id for plan-level work) through every phase:
                     lookups and the enqueue, not the device's work; args
                     ``exchange_bytes`` and ``exchanges``, the plan's
                     inter-chip wire volume (planner.exchange_wire; 0
-                    without a mesh)
+                    without a mesh); ``hoisted_joins`` and
+                    ``inline_joins``, its kernel joins on pooled
+                    layouts and on layouts built in the plan
+                    (planner.kernel_joins)
   plan.device_wait  block_until_ready on what a plan.dispatch enqueued
   merge.partials    morsel-order partial merge (QueryTask._finish)
   result.deliver    terminal-result fan-out (_record)

@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analytics import planner, tpch
 from repro.analytics.columnar import (DENSE_GROUP_LIMIT, dense_layout,
-                                     join_layout, partition_layout)
+                                     join_layout, join_side_layout,
+                                     partition_layout)
 from repro.kernels.hash_aggregate.kernel import (STEP_TILES, TILE,
                                                  hash_aggregate_pallas,
                                                  step_tiles)
@@ -29,6 +30,8 @@ from repro.kernels.radix_partition.kernel import block_histograms_pallas
 
 SF10_ROWS = {"lineitem": 60_000_000, "orders": 15_000_000,
              "customer": 1_500_000, "supplier": 100_000, "nation": 25}
+SF1_ROWS = {"lineitem": 6_000_000, "orders": 1_500_000, "customer": 150_000,
+            "supplier": 10_000, "nation": 25}
 
 
 @pytest.fixture(scope="module")
@@ -51,10 +54,27 @@ def _shape(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _sf10_tables(sharding):
+def _sf10_tables(sharding, rows=SF10_ROWS):
     schema = tpch.generate(scale=0.001).tables
-    return {t: {c: _shape(sharding, (SF10_ROWS[t],), a.dtype)
+    return {t: {c: _shape(sharding, (rows[t],), a.dtype)
                 for c, a in cols.items()} for t, cols in schema.items()}
+
+
+def _pooled_args(sharding, plan, tables):
+    """The build argsorts and kernel-join layouts a dispatch of ``plan``
+    takes from the JoinIndexPool, as abstract arguments."""
+    def placed(tree):
+        return jax.tree.map(lambda a: _shape(sharding, a.shape, a.dtype),
+                            tree)
+
+    indexes = {f"{t}.{c}": placed(jax.eval_shape(
+        lambda k: (jnp.argsort(k), k), tables[t][c]))
+        for t, c in plan.index_specs}
+    layouts = {sp.key: placed(jax.eval_shape(functools.partial(
+        join_side_layout, n_partitions=sp.n_partitions, cap=sp.cap,
+        side=sp.side), tables[sp.table][sp.column]))
+        for sp in plan.joins.layouts}
+    return indexes, layouts
 
 
 def _entry_ops(hlo):
@@ -69,6 +89,15 @@ def _entry_ops(hlo):
                 for shape in re.findall(r"\[([\d,]*)\]", m.group(2))]
         ops.append((m.group(1), m.group(3), max(dims, default=1)))
     return ops
+
+
+def _fusion_calls(hlo, name):
+    """The HLO text of the computation the ENTRY instruction ``name``
+    calls (a fusion's body)."""
+    called = re.search(rf"%{re.escape(name)} = .*?calls=%([\w.-]+)",
+                       hlo).group(1)
+    body = hlo[hlo.index(f"%{called} "):]
+    return body[:body.index("\n}")]
 
 
 def _folded(sharding, rows, dtype):
@@ -167,3 +196,25 @@ def test_q1_cost_plan_stages_no_relayout(one_chip):
     assert not [o for o in ops if o[2] >= big and (
         o[1] in ("copy", "transpose")
         or o[0].startswith(("copy_bitcast_fusion", "transpose")))]
+
+
+def test_q5_kernel_joins_take_pooled_layouts(one_chip):
+    """q5 as the SF 1 join cell runs it (cost executor, Pallas kernel),
+    its argsorts and kernel-join layouts passed in as a dispatch passes
+    them from the JoinIndexPool: every kernel join runs on pooled
+    layouts, so the ENTRY computation holds no sort and no gather or
+    scatter fusion as large as a lineitem layout (12,582,912 slots)."""
+    tables = _sf10_tables(one_chip, SF1_ROWS)
+    ctx = planner.ExecutionContext(executor="cost", mode="pallas")
+    plan = planner.compile_plan(tpch.LOGICAL_QUERIES["q5"], tables, ctx)
+    assert (plan.joins.hoisted, plan.joins.inline) == (3, 0)
+    hlo = plan.fn.lower(tables, *_pooled_args(one_chip, plan, tables)
+                        ).compile().as_text()
+    ops = _entry_ops(hlo)
+    assert any(name.startswith("join_probe") for name, _, _ in ops)
+    assert not [o for o in ops if o[1] == "sort"]
+    big = [name for name, opcode, n in ops
+           if opcode == "fusion" and n >= 12_000_000]
+    assert not [name for name in big
+                if re.search(r" (gather|scatter)\(",
+                             _fusion_calls(hlo, name))]
